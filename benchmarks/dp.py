@@ -172,6 +172,15 @@ def _merge_microbench():
 
 
 def run() -> dict:
+    import jax
+
+    if jax.default_backend() == "tpu":
+        # the worker needs its own JAX runtime, and this process already
+        # holds the chip: a child would fail or hang waiting for it
+        raise RuntimeError(
+            "benchmarks.dp races forced host devices in a child process; "
+            "on a TPU host the parent holds the chip. The four-chip "
+            "data-parallel path runs as `python chip_smoke.py --chips 4`")
     env = dict(os.environ,
                XLA_FLAGS=f"--xla_force_host_platform_device_count={D}")
     out = subprocess.run(

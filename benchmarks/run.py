@@ -27,6 +27,7 @@ from benchmarks import (aos, dp, engine, false_splits, forest,  # noqa: E402
                         kernels, query_sweep, roofline, serve, tree)
 from benchmarks import sketch as sketch_bench  # noqa: E402
 from benchmarks.bench_io import REPO_ROOT, write_bench  # noqa: E402
+from repro.launch.compile_cache import configure_compile_cache  # noqa: E402
 
 
 def _sec_aos(report, csv, args):
@@ -209,6 +210,7 @@ def main() -> None:
                     help="bounded profiler trace (one dispatch per kernel "
                          "family) + per-op compiled costs")
     args = ap.parse_args()
+    configure_compile_cache(REPO_ROOT)
 
     names = args.only or list(SECTIONS)
     if args.skip_aos and "aos" in names:

@@ -57,6 +57,7 @@ same methods on daemon threads for the open-loop deployment shape
 """
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass
@@ -66,6 +67,8 @@ import jax
 import numpy as np
 
 from repro.core import faults as fl
+from repro.core import forest as fr
+from repro.core import hoeffding as ht
 from repro.core import serve as sv
 
 __all__ = ["EngineConfig", "Ticket", "ServingEngine"]
@@ -139,6 +142,16 @@ class Ticket:
         self._event.set()
 
 
+@functools.partial(jax.jit, static_argnums=0)
+def _learn(cfg_model, state, X, y):
+    """One trainer batch as ONE compiled program per batch shape: called
+    eagerly, the update's attempt ``lax.cond`` would be traced and
+    compiled anew on every batch."""
+    if "trees" in state:
+        return fr.update(cfg_model, state, X, y)[0]
+    return ht.update(cfg_model, state, X, y)
+
+
 class _Published:
     """Immutable published record — the single swapped reference.
 
@@ -206,6 +219,11 @@ class ServingEngine:
             "publishes_dropped": 0, "trainer_crashes": 0, "recoveries": 0,
             "ckpt_failures": 0, "stale_events": 0, "max_queue_rows_seen": 0,
         }
+        # the newest caught exception per recovery path, "Type: message"
+        # (None until one happens): a counter alone hides what went wrong
+        self._errors = {"last_trainer_error": None,
+                        "last_publish_error": None,
+                        "last_ckpt_error": None}
         self.publish_from_state()            # version 1: never cold-start
         assert self._published is not None
 
@@ -216,10 +234,16 @@ class ServingEngine:
             for k, v in kv.items():
                 self._metrics[k] += v
 
+    def _note_error(self, key: str, exc: BaseException):
+        with self._m_lock:
+            self._errors[key] = f"{type(exc).__name__}: {exc}"
+
     def metrics(self) -> Dict[str, Any]:
-        """Counter snapshot + the staleness watchdog's current verdict."""
+        """Counter snapshot + the last caught trainer / publish / checkpoint
+        exception (``last_*_error``) + the staleness watchdog's verdict."""
         with self._m_lock:
             out = dict(self._metrics)
+            out.update(self._errors)
         out.update(self.staleness())
         return out
 
@@ -293,7 +317,8 @@ class ServingEngine:
                 self._versions[rec.version] = snap
                 while len(self._versions) > self.cfg.keep_versions:
                     del self._versions[min(self._versions)]
-        except sv.SnapshotValidationError:
+        except sv.SnapshotValidationError as e:
+            self._note_error("last_publish_error", e)
             self._bump(publish_failures=1, rollbacks=1)
             return False
         self._bump(publishes=1)
@@ -306,9 +331,10 @@ class ServingEngine:
         try:
             self._injector.fire("ckpt.save")
             self._ckpt.save(self._trainer_step, self._state, blocking=True)
-        except Exception:
+        except Exception as e:
             # a failed save must never take the trainer down: the last
             # good checkpoint is still on disk and restore skips torn ones
+            self._note_error("last_ckpt_error", e)
             self._bump(ckpt_failures=1)
 
     # -- trainer ----------------------------------------------------------
@@ -319,8 +345,10 @@ class ServingEngine:
         Absorbs ``stream(step)``, advances the step, and at every
         ``sync_every`` boundary freezes + publishes.  Any exception out
         of the step — injected kill or organic — is caught, counted in
-        ``trainer_crashes``, and answered with :meth:`recover`; the
-        engine keeps serving the published snapshot throughout.
+        ``trainer_crashes`` (its type and message kept as
+        ``metrics()["last_trainer_error"]``), and answered with
+        :meth:`recover`; the engine keeps serving the published snapshot
+        throughout.
         """
         batch = self._stream(self._trainer_step)
         if batch is None:
@@ -333,20 +361,15 @@ class ServingEngine:
                 self.publish_from_state()
             elif self.staleness()["stale"]:
                 self._bump(stale_events=1)
-        except Exception:
+        except Exception as e:
+            self._note_error("last_trainer_error", e)
             self._bump(trainer_crashes=1)
             self.recover()
         return True
 
     def _train_step(self, batch):
         X, y = batch
-        if "trees" in self._state:
-            from repro.core import forest as fr
-            state, _aux = fr.update(self._model_cfg, self._state, X, y)
-        else:
-            from repro.core import hoeffding as ht
-            state = ht.update(self._model_cfg, self._state, X, y)
-        return state
+        return _learn(self._model_cfg, self._state, X, y)
 
     def recover(self):
         """Crash recovery: restore the newest valid checkpoint (or fall

@@ -4,11 +4,8 @@ Single-table ops (``qo_update`` / ``qo_best_split``) and the forest-scale
 ops the Hoeffding tree hot path dispatches through (``forest_update`` /
 ``forest_best_splits``).  Every op takes a ``backend``:
 
-* ``"pallas"``    — the compiled kernel: native on TPU, the Triton
-                    lowering on GPU, and the Pallas interpreter as the
-                    fallback everywhere else (so "pallas" is a legal,
-                    if slow, backend on any host — the smoke-test
-                    contract, not TPU-only in principle),
+* ``"pallas"``    — the compiled Mosaic kernel on TPU; anywhere else it
+                    raises instead of silently interpreting,
 * ``"interpret"`` — the same kernel body under Pallas' CPU interpreter
                     (correctness validation against :mod:`repro.kernels.ref`),
 * ``"jnp"``       — a fused pure-jnp lowering of the same math (XLA-fused
@@ -105,13 +102,18 @@ def resolve_backend(backend: str | None) -> str:
 
 def _kernel_interpret(backend: str) -> bool:
     """Interpreter-mode flag for a kernel-path backend: ``"interpret"``
-    always interprets; ``"pallas"`` compiles natively on TPU and GPU
-    (Mosaic / Triton lowerings) and *falls back* to the interpreter on
-    hosts with neither — slow, but correct, so ``backend="pallas"`` is
-    smoke-testable everywhere (the multi-backend contract)."""
+    always interprets; ``"pallas"`` compiles natively on TPU and raises
+    anywhere else — a run that asked for the compiled kernels must never
+    quietly measure the interpreter instead."""
     if backend == "interpret":
         return True
-    return jax.default_backend() not in ("tpu", "gpu")
+    if jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"backend='pallas' compiles the Pallas kernels for a TPU, but "
+            f"JAX's default backend is {jax.default_backend()!r}; use "
+            f"backend='interpret' (the same kernel bodies under the Pallas "
+            f"interpreter) or 'jnp' off-TPU")
+    return False
 
 
 # --------------------------------------------------------------------------
@@ -432,8 +434,8 @@ def _forest_update_impl(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w,
     leaf, X, y, w = _pad_batch(leaf, X, y, w, tile_b)
     dense = pack_forest(ao_y, ao_sum_x, ao_radius, ao_origin, tile_m=tile_m)
     dense = qo_update_leaves_pallas(
-        dense, leaf[None, :], X.T, y[None, :], w[None, :], n_bins=C,
-        tile_b=tile_b, tile_m=tile_m, interpret=_kernel_interpret(backend))
+        dense, leaf[None, :], X.T[:, None, :], y[None, :], w[None, :],
+        n_bins=C, tile_b=tile_b, tile_m=tile_m, interpret=_kernel_interpret(backend))
     return unpack_forest(dense, M, C)
 
 
@@ -719,18 +721,14 @@ def _forest_route_impl(feature, threshold, child, is_leaf, X, *,
     if backend == "jnp":
         return _forest_route_jnp(feature, threshold, child, is_leaf, X,
                                  plies=plies)
-    T, M = feature.shape
     B, F = X.shape
-    attrs = pack_route_attrs(feature, threshold, child, is_leaf,
-                             n_pad=round_up(T * M, 128))
+    attrs = pack_route_attrs(feature, threshold, child, is_leaf)
     tile_b = min(tile_b, round_up(B, 128))
     Bp, Fp = round_up(B, tile_b), round_up(F, 128)
     Xp = jnp.zeros((Bp, Fp), jnp.float32).at[:B, :F].set(X)
-    node0 = jnp.broadcast_to(
-        (jnp.arange(T, dtype=jnp.int32) * M)[:, None], (T, Bp))
-    out = qo_route_pallas(node0, Xp, attrs, plies=plies, tile_b=tile_b,
+    out = qo_route_pallas(Xp, attrs, plies=plies, tile_b=tile_b,
                           interpret=_kernel_interpret(backend))
-    return out[:, :B] - (jnp.arange(T, dtype=jnp.int32) * M)[:, None]
+    return out[:, 0, :B]
 
 
 def _route_single_impl(feature, threshold, child, is_leaf, X, *,

@@ -115,24 +115,27 @@ def _qo_query_batched_kernel(tab_ref, out_ref):
         vr = s2_d - (pn / n_tot) * var(pn, pm2) - (rn / n_tot) * var(rn, rm2)
 
         # ---- neighbouring occupied prototypes via value propagation ------
+        # the "has an occupied bin" flags ride as f32 0/1 masks: Mosaic
+        # cannot lane-shift (concatenate) i1 vectors
         proto = jnp.where(occ, sum_x / jnp.where(occ, n, 1.0), 0.0)
-        lval, lhas = proto, occ          # last occupied value at-or-before i
-        rval, rhas = proto, occ          # first occupied value at-or-after i
+        occf = occ.astype(jnp.float32)
+        lval, lhas = proto, occf         # last occupied value at-or-before i
+        rval, rhas = proto, occf         # first occupied value at-or-after i
         d = 1
         while d < Cp:
             slv = _shift_right(lval, d, 0.0)
-            slh = _shift_right(lhas, d, False)
-            lval = jnp.where(lhas, lval, slv)
-            lhas = jnp.logical_or(lhas, slh)
+            slh = _shift_right(lhas, d, 0.0)
+            lval = jnp.where(lhas > 0, lval, slv)
+            lhas = jnp.maximum(lhas, slh)
             srv = _shift_left(rval, d, 0.0)
-            srh = _shift_left(rhas, d, False)
-            rval = jnp.where(rhas, rval, srv)
-            rhas = jnp.logical_or(rhas, srh)
+            srh = _shift_left(rhas, d, 0.0)
+            rval = jnp.where(rhas > 0, rval, srv)
+            rhas = jnp.maximum(rhas, srh)
             d *= 2
         nval = _shift_left(rval, 1, 0.0)  # first occupied STRICTLY after i
-        nhas = _shift_left(rhas, 1, False)
+        nhas = _shift_left(rhas, 1, 0.0)
 
-        ok = jnp.logical_and(jnp.logical_and(lhas, nhas), att)
+        ok = jnp.logical_and(jnp.logical_and(lhas > 0, nhas > 0), att)
         cand = 0.5 * (lval + nval)
 
         out_ref[0, 0] = jnp.where(ok, vr, -jnp.inf)

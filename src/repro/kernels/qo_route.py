@@ -5,29 +5,31 @@ The read path's hot loop (DESIGN.md §2.6).  The seed routed with
 separate node arrays, re-dispatched per tree by the forest layer.  Here
 routing is the batch-parallel primitive (Pham et al.'s massively-parallel
 traversal model, PAPERS.md): ALL B rows advance through ALL T trees one
-depth ply at a time over a folded SoA node table, one ``pallas_call`` with
+depth ply at a time over SoA node tables, one ``pallas_call`` with
 
     grid = (T, batch-tiles)
 
 so each grid step owns one tree's (tile_b,) slice of row states while the
 (tile_b, Fp) X block is shared across the T grid dimension — the batch is
-never materialized T times.  Node attributes pack into one dense plane:
+never materialized T times.  Each tree's node attributes pack into one
+dense plane, and grid step t reads only tree t's:
 
-    attrs : (Np, 128) f32
+    attrs : (T, Mp, 128) f32
       lane 0: feature   lane 1: threshold   lane 2: left    lane 3: right
 
-with the tree axis folded into global node ids (tree t's node j is row
-``t*M + j`` — the same folded-axis layout as the §5.1 table kernels) and
-leaves self-looped (``left = right = self``), so a settled row keeps
-re-selecting its own leaf and no ``is_leaf`` test exists at all.  Per ply
-the whole transition is one MXU contraction and one compare:
+with tree-local node ids and leaves self-looped (``left = right =
+self``), so a settled row keeps re-selecting its own leaf and no
+``is_leaf`` test exists at all.  Per ply the whole transition is one MXU
+contraction and one compare:
 
-    oh_node : (tile_b, Np)   row r -> its current node
-    a       = oh_node @ attrs                      (tile_b, 128) on the MXU
+    oh_node : (tile_b, Mp)   row r -> its current node
+    a       = oh_node @ attrs[t]                   (tile_b, 128) on the MXU
     x_r     = sum(onehot(feature_r) * X_r)         per-row feature select
     node'   = where(x_r <= threshold_r, left_r, right_r)
 
-The one-hot matmul is exact (a single 1.0 per row), so thresholds and
+The one-hot spans one tree's Mp nodes, not the forest's T·M: its VMEM
+footprint and MXU work stay those of a single tree as T grows.  The
+one-hot matmul is exact (a single 1.0 per row), so thresholds and
 integer ids round-trip bit-identically; routing therefore matches the
 scalar oracle id-for-id on every backend.  ``plies`` (the ply count) is
 static — any count >= the realized tree depth returns identical leaves,
@@ -62,10 +64,9 @@ def fold_route_tables(feature, threshold, child, is_leaf):
     children to global ids and self-loops every leaf, so one transition
     step is a no-op exactly at settled rows.  Returns
     ``(feature, threshold, left, right)``, all (T*M,) — feature/left/right
-    int32, threshold f32.  Shared by every routing backend (the jnp sweep
-    gathers these as one packed row; :func:`pack_route_attrs` lays them
-    across MXU lanes), so the transition relation can never diverge
-    between paths.
+    int32, threshold f32.  The jnp sweep gathers these as one packed row;
+    :func:`pack_route_attrs` builds the same self-looped relation with
+    tree-local ids for the kernel.
     """
     T, M = feature.shape
     N = T * M
@@ -79,84 +80,88 @@ def fold_route_tables(feature, threshold, child, is_leaf):
     return (feature.reshape(N), threshold.reshape(N), left, right)
 
 
-def pack_route_attrs(feature, threshold, child, is_leaf, *,
-                     n_pad: int | None = None) -> jax.Array:
-    """SoA node arrays (T, M) -> the dense (Np, 128) routing plane.
+def pack_route_attrs(feature, threshold, child, is_leaf) -> jax.Array:
+    """SoA node arrays (T, M) -> per-tree (T, Mp, 128) routing planes.
 
-    Rows in [T*M, Np) self-loop, so any start node < Np routes safely.
-    All-f32: node ids stay exact well past 2^24 nodes' worth of any real
-    forest (one-hot contractions copy them bit-exactly).
+    Node ids stay tree-local; leaves and the pad rows in [M, Mp) self-loop,
+    so no reachable transition leaves a tree's plane.  All-f32: node ids
+    are exact far past any real tree's size (one-hot contractions copy
+    them bit-exactly).
     """
-    featg, thr, left, right = fold_route_tables(feature, threshold, child,
-                                                is_leaf)
-    N = featg.shape[0]
-    Np = round_up(max(N if n_pad is None else n_pad, 8), 8)
-    selfloop = jnp.arange(Np, dtype=jnp.float32)                 # pad rows
-    attrs = jnp.zeros((Np, ATTR_LANES), jnp.float32)
-    attrs = attrs.at[:, LANE_FEATURE].set(
-        jnp.zeros((Np,)).at[:N].set(featg.astype(jnp.float32)))
-    attrs = attrs.at[:, LANE_THRESHOLD].set(
-        jnp.zeros((Np,)).at[:N].set(thr))
-    attrs = attrs.at[:, LANE_LEFT].set(
-        selfloop.at[:N].set(left.astype(jnp.float32)))
-    attrs = attrs.at[:, LANE_RIGHT].set(
-        selfloop.at[:N].set(right.astype(jnp.float32)))
-    return attrs
+    T, M = feature.shape
+    Mp = round_up(max(M, 8), 8)
+    own = jnp.arange(M, dtype=jnp.int32)[None, :]
+    left = jnp.where(is_leaf, own, child[..., 0])
+    right = jnp.where(is_leaf, own, child[..., 1])
+    ids = jnp.broadcast_to(jnp.arange(Mp, dtype=jnp.float32), (T, Mp))
+    attrs = jnp.zeros((T, Mp, ATTR_LANES), jnp.float32)
+    attrs = attrs.at[:, :, LANE_LEFT].set(ids).at[:, :, LANE_RIGHT].set(ids)
+    attrs = attrs.at[:, :M, LANE_FEATURE].set(feature.astype(jnp.float32))
+    attrs = attrs.at[:, :M, LANE_THRESHOLD].set(threshold)
+    attrs = attrs.at[:, :M, LANE_LEFT].set(left.astype(jnp.float32))
+    return attrs.at[:, :M, LANE_RIGHT].set(right.astype(jnp.float32))
 
 
-def _qo_route_kernel(node_ref, x_ref, attrs_ref, out_ref, *, plies: int):
-    attrs = attrs_ref[...]                                       # (Np, 128)
+def _qo_route_kernel(x_ref, attrs_ref, out_ref, *, plies: int):
+    attrs = attrs_ref[0]                                         # (Mp, 128)
     x = x_ref[...]                                               # (tile_b, Fp)
-    node = node_ref[0, :].astype(jnp.float32)                    # (tile_b,)
     tile_b, Fp = x.shape
-    Np = attrs.shape[0]
+    Mp = attrs.shape[0]
 
-    slot = jax.lax.broadcasted_iota(jnp.float32, (tile_b, Np), 1)
+    # Mosaic builds integer iotas only; cast to compare with f32 ids
+    slot = jax.lax.broadcasted_iota(jnp.int32, (tile_b, Mp), 1) \
+        .astype(jnp.float32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (tile_b, ATTR_LANES), 1)
-    lane_f = jax.lax.broadcasted_iota(jnp.float32, (tile_b, Fp), 1)
+    lane_f = jax.lax.broadcasted_iota(jnp.int32, (tile_b, Fp), 1) \
+        .astype(jnp.float32)
+    # HIGHEST keeps f32 operands f32 on the MXU: the one-hot gather must
+    # copy thresholds and node ids exactly
     dot = functools.partial(
         jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
+    pick = functools.partial(jnp.sum, axis=1, keepdims=True)
 
-    for _ in range(plies):
-        oh = (node[:, None] == slot).astype(jnp.float32)
+    def ply(_, node):                                            # (tile_b, 1)
+        oh = (node == slot).astype(jnp.float32)
         a = dot(oh, attrs)                                       # (tile_b, 128)
-        f = jnp.sum(jnp.where(lane == LANE_FEATURE, a, 0.0), axis=1)
-        thr = jnp.sum(jnp.where(lane == LANE_THRESHOLD, a, 0.0), axis=1)
-        left = jnp.sum(jnp.where(lane == LANE_LEFT, a, 0.0), axis=1)
-        right = jnp.sum(jnp.where(lane == LANE_RIGHT, a, 0.0), axis=1)
-        xv = jnp.sum(jnp.where(lane_f == f[:, None], x, 0.0), axis=1)
-        node = jnp.where(xv <= thr, left, right)
+        f = pick(jnp.where(lane == LANE_FEATURE, a, 0.0))
+        thr = pick(jnp.where(lane == LANE_THRESHOLD, a, 0.0))
+        left = pick(jnp.where(lane == LANE_LEFT, a, 0.0))
+        right = pick(jnp.where(lane == LANE_RIGHT, a, 0.0))
+        xv = pick(jnp.where(lane_f == f, x, 0.0))
+        return jnp.where(xv <= thr, left, right)
 
-    out_ref[0, :] = node.astype(jnp.int32)
+    # every row starts at its tree's root (local id 0).  The ply loop is
+    # rolled over a 2-D column carry: one body to compile however deep the
+    # bucket (Mosaic cannot carry 1-D vectors through a loop)
+    node = jax.lax.fori_loop(0, plies, ply,
+                             jnp.zeros((tile_b, 1), jnp.float32))
+    out_ref[0, 0, :] = node[:, 0].astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("plies", "tile_b", "interpret"))
-def qo_route_pallas(node0: jax.Array, x: jax.Array, attrs: jax.Array, *,
-                    plies: int, tile_b: int = 256,
-                    interpret: bool = False) -> jax.Array:
-    """node0: (T, Bp) i32 start nodes (global ids); x: (Bp, Fp) f32;
-    attrs: (Np, 128) from :func:`pack_route_attrs`.  Bp must be a multiple
-    of ``tile_b`` (ops.py pads; pad rows route from the root and are
-    sliced off there).  Returns (T, Bp) i32 global leaf ids after
-    ``plies`` transition steps.
+def qo_route_pallas(x: jax.Array, attrs: jax.Array, *, plies: int,
+                    tile_b: int = 256, interpret: bool = False) -> jax.Array:
+    """x: (Bp, Fp) f32; attrs: (T, Mp, 128) from :func:`pack_route_attrs`.
+    Bp must be a multiple of ``tile_b`` (ops.py pads; pad rows route from
+    the root and are sliced off there).  Returns (T, 1, Bp) i32
+    tree-local leaf ids after ``plies`` transition steps from the root.
     """
-    T, Bp = node0.shape
-    Fp = x.shape[1]
-    assert x.shape[0] == Bp and Bp % tile_b == 0
-    assert attrs.shape[1] == ATTR_LANES
+    Bp, Fp = x.shape
+    T, Mp, lanes = attrs.shape
+    assert Bp % tile_b == 0 and lanes == ATTR_LANES
     if plies == 0:
-        return node0
-    grid = (T, Bp // tile_b)
+        return jnp.zeros((T, 1, Bp), jnp.int32)
     return pl.pallas_call(
         functools.partial(_qo_route_kernel, plies=plies),
-        grid=grid,
+        grid=(T, Bp // tile_b),
         in_specs=[
-            pl.BlockSpec((1, tile_b), lambda t, i: (t, i)),       # row states
             pl.BlockSpec((tile_b, Fp), lambda t, i: (i, 0)),      # shared X
-            pl.BlockSpec(attrs.shape, lambda t, i: (0, 0)),       # node plane
+            pl.BlockSpec((1, Mp, ATTR_LANES),
+                         lambda t, i: (t, 0, 0)),                 # tree t's plane
         ],
-        out_specs=pl.BlockSpec((1, tile_b), lambda t, i: (t, i)),
-        out_shape=jax.ShapeDtypeStruct((T, Bp), jnp.int32),
+        out_specs=pl.BlockSpec((1, 1, tile_b), lambda t, i: (t, 0, i)),
+        out_shape=jax.ShapeDtypeStruct((T, 1, Bp), jnp.int32),
         interpret=interpret,
-    )(node0, x, attrs)
+    )(x, attrs)

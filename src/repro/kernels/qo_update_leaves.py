@@ -107,8 +107,8 @@ def _qo_update_leaves_kernel(leaf_ref, x_ref, y_ref, w_ref, tab_ref, out_ref,
         out_ref[...] = tab_ref[...]
 
     Cp = out_ref.shape[3]
-    T = x_ref.shape[1]
-    x = x_ref[0, :]
+    T = x_ref.shape[2]
+    x = x_ref[0, 0, :]
     yv = y_ref[0, :]
     w = w_ref[0, :]
     leaf = leaf_ref[0, :]
@@ -119,8 +119,10 @@ def _qo_update_leaves_kernel(leaf_ref, x_ref, y_ref, w_ref, tab_ref, out_ref,
     oh_leaf = (lloc[:, None] == slot).astype(jnp.float32)
 
     # per-row radius/origin: gather via MXU, read back from lane 0
+    # (HIGHEST keeps f32 operands f32 on the MXU here and below)
     dot_lm = functools.partial(
         jax.lax.dot_general, dimension_numbers=(((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     lane = jax.lax.broadcasted_iota(jnp.int32, (T, Cp), 1)
     r_row = jnp.sum(jnp.where(lane == 0, dot_lm(oh_leaf, out_ref[0, ROW_RADIUS]),
@@ -137,6 +139,7 @@ def _qo_update_leaves_kernel(leaf_ref, x_ref, y_ref, w_ref, tab_ref, out_ref,
     # (tile_m, Cp) <- (T, tile_m)^T @ (T, Cp) contractions on the MXU
     contract = functools.partial(
         jax.lax.dot_general, dimension_numbers=(((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     n_b = contract(oh_leaf, wbin)
     sx_b = contract(oh_leaf, wbin * x[:, None])
@@ -171,14 +174,14 @@ def qo_update_leaves_pallas(tab: jax.Array, leaf: jax.Array, x: jax.Array,
                             y: jax.Array, w: jax.Array, *, n_bins: int,
                             tile_b: int = 256, tile_m: int = 128,
                             interpret: bool = False) -> jax.Array:
-    """tab: (F, 8, Mp, Cp); leaf: (1, Bp) i32; x: (F, Bp); y/w: (1, Bp).
+    """tab: (F, 8, Mp, Cp); leaf: (1, Bp) i32; x: (F, 1, Bp); y/w: (1, Bp).
 
     Bp must be a multiple of ``tile_b`` and Mp of ``tile_m`` (ops.py pads
     with w = 0 / leaf = -1).  Returns the merged dense forest.
     """
     F, rows, Mp, Cp = tab.shape
     assert rows == FOREST_ROWS
-    Bp = x.shape[1]
+    Bp = x.shape[2]
     assert Bp % tile_b == 0 and Mp % tile_m == 0
     grid = (F, Mp // tile_m, Bp // tile_b)
 
@@ -189,7 +192,7 @@ def qo_update_leaves_pallas(tab: jax.Array, leaf: jax.Array, x: jax.Array,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # leaf ids
-            pl.BlockSpec((1, tile_b), lambda f, j, i: (f, i)),    # x feature
+            pl.BlockSpec((1, 1, tile_b), lambda f, j, i: (f, 0, i)),  # x feature
             pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # y
             pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # w
             pl.BlockSpec((1, FOREST_ROWS, tile_m, Cp),

@@ -12,7 +12,7 @@ each bucket with the exact grouped two-pass (n, mean, M2) form:
 
 with the (T·M, F) table axes flattened to R rows (same packing idiom as
 ``qo_merge``), J input centroids and K output buckets each padded to the
-128-lane tile.  Per output bucket k (static unrolled loop — K is a
+128-lane tile.  Per output bucket k (a rolled ``fori_loop`` — K is a
 config constant, typically 8-64):
 
     mask_k = (bucket == k)                            VPU compare
@@ -73,13 +73,12 @@ def unpack_compact_planes(dense: jax.Array, lead, k_out: int):
 def _sketch_compact_kernel(a_ref, o_ref, *, k_out: int):
     n, mean, m2, sx, bk = (a_ref[i] for i in range(5))
     tile_r, Kp = n.shape[0], o_ref.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.float32, (tile_r, Kp), 1)
-    out_n = jnp.zeros((tile_r, Kp), jnp.float32)
-    out_mean = jnp.zeros((tile_r, Kp), jnp.float32)
-    out_m2 = jnp.zeros((tile_r, Kp), jnp.float32)
-    out_sx = jnp.zeros((tile_r, Kp), jnp.float32)
-    for k in range(k_out):
-        mask = (bk == k).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (tile_r, Kp), 1) \
+        .astype(jnp.float32)            # Mosaic builds integer iotas only
+
+    def bucket(k, acc):
+        out_n, out_mean, out_m2, out_sx = acc
+        mask = (bk == k.astype(jnp.float32)).astype(jnp.float32)
         n_k = jnp.sum(mask * n, axis=-1)
         sy_k = jnp.sum(mask * n * mean, axis=-1)
         sx_k = jnp.sum(mask * sx, axis=-1)
@@ -87,11 +86,17 @@ def _sketch_compact_kernel(a_ref, o_ref, *, k_out: int):
         mean_k = jnp.where(occ, sy_k / jnp.where(occ, n_k, 1.0), 0.0)
         d = mean - mean_k[:, None]
         m2_k = jnp.where(occ, jnp.sum(mask * (m2 + n * d * d), axis=-1), 0.0)
-        col = (lane == k).astype(jnp.float32)
-        out_n = out_n + n_k[:, None] * col
-        out_mean = out_mean + mean_k[:, None] * col
-        out_m2 = out_m2 + m2_k[:, None] * col
-        out_sx = out_sx + sx_k[:, None] * col
+        col = (lane == k.astype(jnp.float32)).astype(jnp.float32)
+        return (out_n + n_k[:, None] * col,
+                out_mean + mean_k[:, None] * col,
+                out_m2 + m2_k[:, None] * col,
+                out_sx + sx_k[:, None] * col)
+
+    # a rolled loop keeps one bucket's temporaries live at a time: the
+    # unrolled form outgrows the 16 MiB scoped VMEM at K = 64
+    zero = jnp.zeros((tile_r, Kp), jnp.float32)
+    out_n, out_mean, out_m2, out_sx = jax.lax.fori_loop(
+        0, k_out, bucket, (zero, zero, zero, zero))
     o_ref[0] = out_n
     o_ref[1] = out_mean
     o_ref[2] = out_m2

@@ -131,7 +131,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         return result
 
     from repro.launch import hlocost
-    cost = hlocost.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     # trip-count-aware walk (cost_analysis counts scan bodies once)

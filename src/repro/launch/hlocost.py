@@ -71,15 +71,6 @@ SKIP_OPS = {"parameter", "constant", "tuple", "get-tuple-element", "bitcast",
             "exponential-minus-one", "log-plus-one", "atan2", "cosine", "sine"}
 
 
-def cost_dict(compiled) -> Dict[str, float]:
-    """``compiled.cost_analysis()`` across jax versions: older jax returns
-    one dict per partition, newer a single dict — normalize to a dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def _shape_bytes(type_str: str) -> int:
     total = 0
     for dt, dims in _SHAPE.findall(type_str):

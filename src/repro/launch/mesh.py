@@ -11,14 +11,10 @@ __all__ = ["make_mesh_auto", "make_production_mesh", "make_local_mesh"]
 
 
 def make_mesh_auto(shape, axes):
-    """``jax.make_mesh`` with explicit Auto axis types where this jax
-    version supports them (``axis_types`` landed after 0.4.37; Auto is the
-    default either way)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of the Auto type (sharding left
+    to the compiler, as :func:`jax.jit` in/out shardings expect)."""
     return jax.make_mesh(shape, axes,
-                         axis_types=(axis_type.Auto,) * len(axes))
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -13,10 +13,6 @@ Two thin, dependency-free views into what the kernels actually cost:
   (:mod:`benchmarks.roofline` uses its own analytic model instead, so
   the roofline gate cannot drift when XLA's cost tables change; the two
   are cross-checkable in the profile report).
-
-Both normalize across jax versions via
-:func:`repro.launch.hlocost.cost_dict` (older jax returns
-``cost_analysis()`` as a one-element list).
 """
 from __future__ import annotations
 
@@ -25,8 +21,6 @@ import json
 import os
 
 import jax
-
-from repro.launch import hlocost
 
 __all__ = ["trace", "op_costs", "profile_ops", "write_report"]
 
@@ -58,7 +52,7 @@ def op_costs(fn, *args, static_argnames=()) -> dict:
     jitted = fn if hasattr(fn, "lower") else jax.jit(
         fn, static_argnames=static_argnames)
     compiled = jitted.lower(*args).compile()
-    cost = hlocost.cost_dict(compiled)
+    cost = compiled.cost_analysis()
     out = {
         "flops": float(cost.get("flops", 0.0)),
         "bytes": float(cost.get("bytes accessed", 0.0)),

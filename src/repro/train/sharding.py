@@ -22,7 +22,8 @@ from typing import Any, Dict, NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
+                          SingleDeviceSharding)
 
 
 def mesh_axes(mesh: Mesh) -> Tuple[Tuple[str, ...], str]:
@@ -264,8 +265,6 @@ def build_sharded_forest(fcfg, mesh: Mesh, axis: str = "data"):
     so under simultaneous drift a D-way sharded forest may reset up to D
     members per batch where the single-host forest resets one.
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.core import forest as fr
 
     assert fcfg.n_trees % mesh.shape[axis] == 0, \
@@ -276,17 +275,17 @@ def build_sharded_forest(fcfg, mesh: Mesh, axis: str = "data"):
     aux_spec = {"member_mse": P(axis), "forest_mse": P(),
                 "drift": P(axis)}
 
-    # check_rep=False: the member update routes with fori_loop (lowered
-    # to `while`, which has no replication rule in this jax); the P()
-    # outputs are replicated by construction (psum)
-    upd = shard_map(
+    # check_vma=False: the P() outputs are replicated by construction
+    # (psum), which the varying-manual-axes check cannot see through the
+    # member update's gathers
+    upd = jax.shard_map(
         lambda s, X, y: fr.update(fcfg, s, X, y, axis_name=axis),
         mesh=mesh, in_specs=(sspec, P(None, None), P(None)),
-        out_specs=(sspec, aux_spec), check_rep=False)
-    prd = shard_map(
+        out_specs=(sspec, aux_spec), check_vma=False)
+    prd = jax.shard_map(
         lambda s, X: fr.predict(fcfg, s, X, axis_name=axis),
         mesh=mesh, in_specs=(sspec, P(None, None)), out_specs=P(None),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(upd), jax.jit(prd)
 
 
@@ -309,8 +308,6 @@ def build_sharded_serving(snap, mesh: Mesh, axis: str = "data"):
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
-
     from repro.core import serve as sv
 
     plies = sv.kops.depth_bucket(snap.depth)
@@ -324,10 +321,10 @@ def build_sharded_serving(snap, mesh: Mesh, axis: str = "data"):
     # depth merely CHANGED (shallower included) with a treedef mismatch
     # instead of serving it
     specs = tuple(P(*([None] * a.ndim)) for a in arrays)
-    # check_rep off: the routing sweep's gathers have no replication rule
-    fn = jax.jit(shard_map(
+    # check_vma off: the routing sweep's gathers have no replication rule
+    fn = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=specs + (P(axis, None),),
-        out_specs=P(axis), check_rep=False))
+        out_specs=P(axis), check_vma=False))
 
     def predict_fn(s, X):
         if s.single != snap.single or s.depth > plies:
@@ -454,6 +451,18 @@ def _dp_local_window(fcfg, forest, delta, keys, Xw, yw):
 
     (delta, keys), _ = jax.lax.scan(body, (delta, keys), (Xw, yw))
     return delta, keys
+
+
+def _dp_block(local_fn, fcfg, forest, delta, keys, X, y):
+    """One device's program: ``local_fn`` (:func:`_dp_local_shard` or
+    :func:`_dp_local_window`) on a (1, ...) block of the stacked shard
+    state.  The mesh trainer runs it under ``shard_map``; the reference
+    jits the SAME function and runs it shard by shard, so both compile
+    one shard's program.  (A ``vmap`` over the shard axis is a different
+    program: on a TPU, XLA reduces it in another order.)"""
+    d, k = jax.tree.map(lambda a: a[0], (delta, keys))
+    d, k = local_fn(fcfg, forest, d, k, X, y)
+    return jax.tree.map(lambda a: a[None], (d, k))
 
 
 def _dp_reduce_deltas(fcfg, delta):
@@ -660,12 +669,13 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
     over ``D = mesh.shape[axis]`` devices.  Every device owns a
     replicated copy of the forest (topology + quantization grids +
     merged stats) and a private delta; a local step is route/absorb
-    only, and every ``sync_every`` batches the deltas all-reduce with
-    the Chan-merge collective (:func:`repro.kernels.ops.forest_merge`)
-    and the split attempts execute on the merged statistics — identical
-    on every device, so the D-shard forest is bit-identical to the
-    single-device execution of the same protocol at every sync boundary
-    (pinned by tests against :func:`build_data_parallel_reference`).
+    only, and every ``sync_every`` batches the deltas gather to the
+    mesh's first device, reduce there with the Chan-merge
+    (:func:`repro.kernels.ops.forest_merge`), the split attempts execute
+    on the merged statistics, and the new forest is broadcast back — so
+    the D-shard forest is bit-identical to the single-device execution
+    of the same protocol at every sync boundary (pinned by tests
+    against :func:`build_data_parallel_reference`).
 
     ``sync_every`` trades collective traffic for split latency: between
     syncs no leaf can split (statistics keep absorbing; nothing is
@@ -697,8 +707,6 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
     ``sync_every`` cadence directly.  Exceptions out of ``on_sync`` are
     the CALLER's (a publish failure must not poison training).
     """
-    from jax.experimental.shard_map import shard_map
-
     from repro.core import forest as fr
 
     assert fcfg.tree.split_backend != "oracle", \
@@ -716,44 +724,39 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
     kspec = P(axis, None, None)
     forest_repl = to_shardings(mesh, fspec)
     delta_shard = to_shardings(mesh, dspec)
-    delta_repl = to_shardings(mesh, repl(abstract["delta"]))
+    # the sync runs on ONE device: its merge and attempt kernels are
+    # Mosaic calls, which XLA cannot partition over a mesh, and on one
+    # device it is the very executable the reference runs
+    root = SingleDeviceSharding(mesh.devices.flat[0])
 
-    def local_body(forest, delta, keys, X, y):
-        d, k = jax.tree.map(lambda a: a[0], (delta, keys))
-        d, k = _dp_local_shard(fcfg, forest, d, k, X, y)
-        return jax.tree.map(lambda a: a[None], (d, k))
-
-    # check_rep off: routing/absorb gathers have no replication rule
-    local = jax.jit(shard_map(
-        local_body, mesh=mesh,
+    # check_vma off: routing/absorb gathers have no replication rule
+    local = jax.jit(jax.shard_map(
+        functools.partial(_dp_block, _dp_local_shard, fcfg), mesh=mesh,
         in_specs=(fspec, dspec, kspec, P(axis, None), P(axis)),
-        out_specs=(dspec, kspec), check_rep=False))
+        out_specs=(dspec, kspec), check_vma=False))
 
-    def window_body(forest, delta, keys, Xw, yw):
-        d, k = jax.tree.map(lambda a: a[0], (delta, keys))
-        d, k = _dp_local_window(fcfg, forest, d, k, Xw, yw)
-        return jax.tree.map(lambda a: a[None], (d, k))
-
-    window = jax.jit(shard_map(
-        window_body, mesh=mesh,
+    window = jax.jit(jax.shard_map(
+        functools.partial(_dp_block, _dp_local_window, fcfg), mesh=mesh,
         in_specs=(fspec, dspec, kspec, P(None, axis, None), P(None, axis)),
-        out_specs=(dspec, kspec), check_rep=False))
+        out_specs=(dspec, kspec), check_vma=False))
 
     if compress == "int8":
-        gather = jax.jit(shard_map(
+        gather = jax.jit(jax.shard_map(
             lambda delta: _dp_gather_int8(
                 fcfg, jax.tree.map(lambda a: a[0], delta), axis),
             mesh=mesh, in_specs=(dspec,),
             out_specs=repl(jax.eval_shape(
                 lambda d: jax.tree.map(lambda a: a[0], d),
-                abstract["delta"])), check_rep=False))
+                abstract["delta"])), check_vma=False))
         sync = lambda forest, delta: _dp_apply_jit(fcfg)(
-            forest, gather(delta))
+            jax.device_put(forest, root),
+            jax.device_put(gather(delta), root))
     else:
-        # the all-gather is the collective; reduce + apply then run
-        # replicated through the SAME jit as the reference
+        # the gather to the root device is the collective; reduce + apply
+        # run there through the SAME jit as the reference, and the merged
+        # forest is broadcast back (``_synced``)
         sync = lambda forest, delta: _dp_sync_jit(fcfg)(
-            forest, jax.device_put(delta, delta_repl))
+            jax.device_put(forest, root), jax.device_put(delta, root))
 
     zero_delta = jax.device_put(_dp_init_delta(fcfg, D), delta_shard)
 
@@ -789,10 +792,10 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
         return _synced(dpstate, delta, keys,
                        dpstate["step"] + Xw.shape[0])
 
-    prd = jax.jit(shard_map(
+    prd = jax.jit(jax.shard_map(
         lambda forest, X: fr.predict(fcfg, forest, X),
         mesh=mesh, in_specs=(fspec, P(axis, None)), out_specs=P(axis),
-        check_rep=False))
+        check_vma=False))
 
     return DataParallelForest(init_fn, update_fn, update_window_fn,
                               lambda dpstate, X: prd(dpstate["forest"], X))
@@ -802,33 +805,36 @@ def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1,
                                   on_sync=None):
     """Single-device oracle of :func:`build_data_parallel_forest`.
 
-    The SAME protocol with the shard axis as a local ``vmap`` instead of
-    a mesh axis — every local step runs the identical per-shard body on
-    the identical slices, and the sync boundary goes through the very
-    same cached jit (:func:`_dp_sync_jit`).  The sharded trainer is
-    pinned bitwise against this at every sync boundary
-    (tests/test_dp.py): the mesh placement is an execution choice, not
-    a semantics change.
+    The SAME protocol with the shards run one after another on one
+    device instead of across a mesh axis — every local step runs the
+    identical per-shard program (:func:`_dp_block`) on the identical
+    slices, and the sync boundary goes through the very same cached jit
+    (:func:`_dp_sync_jit`).  The sharded trainer is pinned bitwise
+    against this at every sync boundary (tests/test_dp.py): the mesh
+    placement is an execution choice, not a semantics change.
     """
     from repro.core import forest as fr
 
     assert fcfg.tree.split_backend != "oracle"
 
-    local = jax.jit(jax.vmap(
-        functools.partial(_dp_local_shard, fcfg),
-        in_axes=(None, 0, 0, 0, 0)))
-    window = jax.jit(jax.vmap(
-        functools.partial(_dp_local_window, fcfg),
-        in_axes=(None, 0, 0, 1, 1)))
+    local = jax.jit(functools.partial(_dp_block, _dp_local_shard, fcfg))
+    window = jax.jit(functools.partial(_dp_block, _dp_local_window, fcfg))
 
     def init_fn(key):
         return init_data_parallel(fcfg, key, n_shards)
 
-    def _shardwise(X, y):
-        B = y.shape[-1] if y.ndim > 1 else y.shape[0]
+    def _shardwise(block, dpstate, X, y):
+        """Run ``block`` on each shard's contiguous rows (the rows
+        ``P(axis)`` gives mesh device i) and restack the shard axis."""
+        B = y.shape[-1]
         assert B % n_shards == 0, (B, n_shards)
-        shp = X.shape[:-2] + (n_shards, B // n_shards)
-        return X.reshape(shp + X.shape[-1:]), y.reshape(shp)
+        b = B // n_shards
+        outs = [block(dpstate["forest"],
+                      jax.tree.map(lambda a: a[i:i + 1], dpstate["delta"]),
+                      dpstate["keys"][i:i + 1],
+                      X[..., i * b:(i + 1) * b, :], y[..., i * b:(i + 1) * b])
+                for i in range(n_shards)]
+        return jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
 
     def _synced(dpstate, delta, keys, step):
         forest, aux = _dp_sync_jit(fcfg)(dpstate["forest"], delta)
@@ -839,18 +845,14 @@ def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1,
                 "keys": keys, "step": step}, aux
 
     def update_fn(dpstate, X, y):
-        Xs, ys = _shardwise(X, y)
-        delta, keys = local(dpstate["forest"], dpstate["delta"],
-                            dpstate["keys"], Xs, ys)
+        delta, keys = _shardwise(local, dpstate, X, y)
         step = dpstate["step"] + 1
         if step % sync_every:
             return dict(dpstate, delta=delta, keys=keys, step=step), None
         return _synced(dpstate, delta, keys, step)
 
     def update_window_fn(dpstate, Xw, yw):
-        Xs, ys = _shardwise(Xw, yw)                  # (S, D, B/D, ...)
-        delta, keys = window(dpstate["forest"], dpstate["delta"],
-                             dpstate["keys"], Xs, ys)
+        delta, keys = _shardwise(window, dpstate, Xw, yw)
         return _synced(dpstate, delta, keys,
                        dpstate["step"] + Xw.shape[0])
 

@@ -29,15 +29,6 @@ from repro.train import sharding as SH
 from repro.train import monitor as MON
 
 
-def _ensure_sharding_invariant_rng():
-    """Initializing params under different meshes must produce identical
-    weights; older jax defaults partitionable threefry off, making the same
-    PRNGKey yield different bits per out_sharding.  Set when a sharded step
-    is built rather than at import (global config mutation stays tied to an
-    explicit API call)."""
-    jax.config.update("jax_threefry_partitionable", True)
-
-
 def input_specs(cfg, shape, *, abstract=True):
     """ShapeDtypeStruct stand-ins for every model input of a shape config.
 
@@ -79,16 +70,10 @@ def build_train_step(cfg, shape, mesh, opt_cfg=None, *, microbatch: int = 0,
                      sharding_style="contraction"):
     """Returns (step_fn, in_shardings, out_shardings, arg_shapes).
 
-    Side effect: enables ``jax_threefry_partitionable`` process-wide so
-    param init under any mesh yields identical weights (see
-    :func:`_ensure_sharding_invariant_rng`) — jax.random bits drawn after
-    the first builder call differ from a process that never built a step.
-
     seq_parallel: pin the residual stream sequence-sharded over the model
     axis (Megatron-SP).  Row-parallel all-reduces of (tokens, d) outputs
     become reduce-scatter + all-gather pairs — ~TP-fold fewer collective
     bytes on the residual (§Perf hillclimb)."""
-    _ensure_sharding_invariant_rng()
     opt_cfg = opt_cfg or adamw.AdamWConfig()
     pshapes = abstract_params(cfg)
     pspecs = SH.param_specs(cfg, pshapes, mesh, style=sharding_style)
@@ -162,7 +147,6 @@ def build_serve_steps(cfg, shape, mesh, *, kv_chunk=512):
 
     decode shapes lower ``serve_step`` = one token against a seq_len cache.
     """
-    _ensure_sharding_invariant_rng()
     pshapes = abstract_params(cfg)
     pspecs = SH.param_specs(cfg, pshapes, mesh)
     B, S = shape.global_batch, shape.seq_len

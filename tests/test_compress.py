@@ -34,13 +34,12 @@ def test_error_feedback_is_lossless_over_time(rng):
 
 
 def test_int8_quantized_psum_single_device(rng):
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh_auto
     mesh = make_mesh_auto((1,), ("pod",))
     g = jnp.array(rng.normal(0, 0.1, (64,)).astype(np.float32))
 
-    out = shard_map(
+    out = jax.shard_map(
         lambda x: compress.quantized_psum({"g": x}, "pod")["g"],
         mesh=mesh, in_specs=P(), out_specs=P())(g)
     np.testing.assert_allclose(np.asarray(out), np.asarray(g),

@@ -109,6 +109,9 @@ def test_trainer_kill_mid_window_serving_uninterrupted(tmp_path):
 
     m = e.metrics()
     assert m["trainer_crashes"] == 1 and m["recoveries"] == 1
+    # the swallowed exception stays readable, type and message
+    assert m["last_trainer_error"].startswith("TrainerKilled: ")
+    assert m["last_publish_error"] is None
     # zero failed requests: everything admitted was served, bit-identically
     assert all(t.status == "done" for t in tickets)
     for t in tickets:
@@ -147,6 +150,9 @@ def test_recovery_without_checkpointer_falls_back_to_memory():
     e.train_once()
     m = e.metrics()
     assert m["trainer_crashes"] == 1 and m["recoveries"] == 1
+    # the swallowed exception stays readable, type and message
+    assert m["last_trainer_error"].startswith("TrainerKilled: ")
+    assert m["last_publish_error"] is None
     assert e._trainer_step == step           # in-memory state kept
     assert e.published_version >= 2          # still re-published
 
@@ -195,6 +201,8 @@ def test_corrupt_vote_weights_and_child_range_rejected():
         version=jnp.int32(99), step=jnp.int32(99))
     assert not e.publish(bad_child)
     assert e.metrics()["rollbacks"] == 2
+    err = e.metrics()["last_publish_error"]
+    assert err.startswith("SnapshotValidationError: ") and "child" in err
 
 
 # -- fault: dropped publishes -> staleness watchdog ------------------------
@@ -264,8 +272,10 @@ def test_inflight_requests_drain_on_the_pinned_version():
 def test_threaded_engine_serves_everything_admitted(tmp_path):
     inj = fl.FaultInjector()
     inj.arm("trainer.step", fl.Kill(), after=3)
+    # the free-running trainer may publish many versions while tickets
+    # wait: retain them all, so every ticket's version stays auditable
     e = make_engine(tmp_path, inj, sync_every=2, max_queue_rows=4096,
-                    max_batch_rows=512)
+                    max_batch_rows=512, keep_versions=1 << 30)
     e.start()
     try:
         tickets = [e.submit(X_ALL[i % 32:(i % 32) + 48]) for i in range(20)]
@@ -284,6 +294,9 @@ def test_threaded_engine_serves_everything_admitted(tmp_path):
         e.stop(drain=True)
     m = e.metrics()
     assert m["trainer_crashes"] == 1 and m["recoveries"] == 1
+    # the swallowed exception stays readable, type and message
+    assert m["last_trainer_error"].startswith("TrainerKilled: ")
+    assert m["last_publish_error"] is None
     assert all(t.status == "done" for t in admitted)
     assert m["served_requests"] == len(admitted)
     assert m["served_rows"] + m["shed_rows"] == sum(t.rows for t in tickets)

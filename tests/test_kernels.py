@@ -147,12 +147,12 @@ def test_qo_update_clamp_bit_identical_across_tiles(B, rng):
 
 
 def test_pallas_backend_falls_back_off_tpu(rng):
-    """backend="pallas" on a host with neither TPU nor GPU must run the
-    kernel under the interpreter (the multi-backend smoke contract) and
-    agree with the jnp lowering — not fail to compile."""
-    if jax.default_backend() in ("tpu", "gpu"):
+    """backend="pallas" off-TPU must refuse to run (no silent fallback
+    to the interpreter, which would pass interpreter numbers off as the
+    compiled kernel's), while the explicit "interpret" backend runs the
+    same kernel body and agrees with the jnp lowering."""
+    if jax.default_backend() == "tpu":
         pytest.skip("native kernel path exists here")
-    assert ops._kernel_interpret("pallas") is True
     assert ops._kernel_interpret("interpret") is True
     M, F, C, B = 16, 3, 8, 64
     from repro.core import stats
@@ -163,8 +163,13 @@ def test_pallas_backend_falls_back_off_tpu(rng):
     leaf = jnp.array(rng.integers(0, M, B), jnp.int32)
     X = jnp.array(rng.normal(0, 1, (B, F)).astype(np.float32))
     y = jnp.array(rng.normal(0, 1, B).astype(np.float32))
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops._kernel_interpret("pallas")
+    with pytest.raises(RuntimeError, match="TPU"):
+        ops.forest_update(ao_y, ao_sum_x, ao_radius, ao_origin,
+                          leaf, X, y, backend="pallas")
     ky, ksx = ops.forest_update(ao_y, ao_sum_x, ao_radius, ao_origin,
-                                leaf, X, y, backend="pallas")
+                                leaf, X, y, backend="interpret")
     jy, jsx = ops.forest_update(ao_y, ao_sum_x, ao_radius, ao_origin,
                                 leaf, X, y, backend="jnp")
     np.testing.assert_allclose(np.asarray(ky["n"]), np.asarray(jy["n"]),

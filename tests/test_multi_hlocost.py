@@ -63,7 +63,7 @@ def test_walker_counts_scan_trip_counts():
     r = hlocost.analyze(comp.as_text())
     assert r["flops"] == 5 * 2 * 64 ** 3
     # raw cost_analysis counts the body once — the walker must not
-    assert hlocost.cost_dict(comp)["flops"] < r["flops"]
+    assert comp.cost_analysis()["flops"] < r["flops"]
 
 
 def test_walker_nested_scans_multiply():

@@ -62,7 +62,6 @@ def test_distributed_sketch_merge_8_devices():
     """QO tables merged across a real 8-way axis == single-stream table."""
     code = """
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.core import qo, sketch
     from repro.launch.mesh import make_mesh_auto
@@ -74,7 +73,7 @@ def test_distributed_sketch_merge_8_devices():
         t = qo.update(qo.init(64, radius=0.2), xs, xs)
         return sketch.all_merge(t, "data")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_rep=False))(
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"), out_specs=P(), check_vma=False))(
         jnp.array(x))
     ref = qo.update(qo.init(64, radius=0.2), jnp.array(x), jnp.array(x))
     np.testing.assert_allclose(np.asarray(out["y"]["n"]),
@@ -92,7 +91,6 @@ def test_distributed_sketch_merge_8_devices():
 def test_int8_quantized_psum_8_devices():
     code = """
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.optim import compress
     from repro.launch.mesh import make_mesh_auto
@@ -100,9 +98,9 @@ def test_int8_quantized_psum_8_devices():
     rng = np.random.default_rng(0)
     g = rng.normal(0, 0.1, (8, 128)).astype(np.float32)
 
-    out = jax.jit(shard_map(
+    out = jax.jit(jax.shard_map(
         lambda x: compress.quantized_psum({"g": x[0]}, "pod")["g"],
-        mesh=mesh, in_specs=P("pod"), out_specs=P(), check_rep=False))(jnp.array(g))
+        mesh=mesh, in_specs=P("pod"), out_specs=P(), check_vma=False))(jnp.array(g))
     ref = g.sum(0)
     err = np.abs(np.asarray(out) - ref).max()
     scale = np.abs(g).max() / 127 * 8

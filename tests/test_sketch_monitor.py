@@ -20,7 +20,6 @@ def test_quantile_accuracy(rng):
 def test_all_merge_across_devices():
     """shard_map all_merge == single-stream table (1 device => trivial but
     exercises the collective path; multi-device covered in test_sharding)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.mesh import make_mesh_auto
     mesh = make_mesh_auto((1,), ("d",))
@@ -31,8 +30,8 @@ def test_all_merge_across_devices():
         t = qo.update(qo.init(64, radius=0.2), xs, xs)
         return sketch.all_merge(t, "d")
 
-    out = jax.jit(shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(),
-                            check_rep=False))(jnp.array(x))
+    out = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(),
+                                check_vma=False))(jnp.array(x))
     ref = qo.update(qo.init(64, radius=0.2), jnp.array(x), jnp.array(x))
     np.testing.assert_allclose(np.asarray(out["y"]["n"]),
                                np.asarray(ref["y"]["n"]), atol=1e-3)

@@ -57,6 +57,7 @@ same methods on daemon threads for the open-loop deployment shape
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import threading
 import time
@@ -218,6 +219,7 @@ class ServingEngine:
             "publishes": 0, "publish_failures": 0, "rollbacks": 0,
             "publishes_dropped": 0, "trainer_crashes": 0, "recoveries": 0,
             "ckpt_failures": 0, "stale_events": 0, "max_queue_rows_seen": 0,
+            "publish_host_s": 0.0, "publish_wait_s": 0.0,
         }
         # the newest caught exception per recovery path, "Type: message"
         # (None until one happens): a counter alone hides what went wrong
@@ -240,7 +242,13 @@ class ServingEngine:
 
     def metrics(self) -> Dict[str, Any]:
         """Counter snapshot + the last caught trainer / publish / checkpoint
-        exception (``last_*_error``) + the staleness watchdog's verdict."""
+        exception (``last_*_error``) + the staleness watchdog's verdict.
+
+        ``publish_wait_s`` sums the seconds publishes spent reading the
+        live state back from the device (``serve.freeze.fetch``, which
+        first waits for every step still queued there);
+        ``publish_host_s`` the rest of every ``engine.publish`` span:
+        host work while the device has nothing queued."""
         with self._m_lock:
             out = dict(self._metrics)
             out.update(self._errors)
@@ -277,13 +285,29 @@ class ServingEngine:
         hook; the last ``cfg.keep_versions`` publishes are retained)."""
         return self._versions[version]
 
+    @contextlib.contextmanager
+    def _publishing(self):
+        """The ``engine.publish`` span.  Yields a dict for
+        :func:`repro.core.serve.freeze`'s ``timings``; its ``fetch``
+        seconds go to ``publish_wait_s``, the span's other seconds to
+        ``publish_host_s``."""
+        timings = {"fetch": 0.0}
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("engine.publish"):
+            yield timings
+        wait = timings["fetch"]
+        self._bump(publish_wait_s=wait,
+                   publish_host_s=time.perf_counter() - t0 - wait)
+
     def publish_from_state(self) -> bool:
         """Freeze the live trainer state and offer it for publication."""
-        with self._pub_lock:
-            version = (self._published.version + 1) if self._published else 1
-        snap = sv.freeze(self._state, version=version,
-                         step=self._trainer_step)
-        return self.publish(snap)
+        with self._publishing() as timings:
+            with self._pub_lock:
+                version = (self._published.version + 1) \
+                    if self._published else 1
+            snap = sv.freeze(self._state, version=version,
+                             step=self._trainer_step, timings=timings)
+            return self._publish(snap)
 
     def publish(self, snap: sv.Snapshot) -> bool:
         """Validate → atomically swap; False = rejected (rollback).
@@ -297,6 +321,11 @@ class ServingEngine:
         one immutable record under ``_pub_lock`` and retains the
         version for audits.
         """
+        with self._publishing():
+            return self._publish(snap)
+
+    def _publish(self, snap: sv.Snapshot) -> bool:
+        """The body of :meth:`publish`, inside its span."""
         try:
             snap = self._injector.fire("publish", snap)
         except fl.DropSignal:
@@ -304,7 +333,8 @@ class ServingEngine:
             return False
         try:
             sv.validate_snapshot(snap)
-            with self._pub_lock:
+            with jax.profiler.TraceAnnotation("engine.swap"), \
+                    self._pub_lock:
                 if (self._published is not None
                         and int(np.asarray(snap.version))
                         <= self._published.version):
@@ -329,8 +359,10 @@ class ServingEngine:
 
     def _checkpoint(self):
         try:
-            self._injector.fire("ckpt.save")
-            self._ckpt.save(self._trainer_step, self._state, blocking=True)
+            with jax.profiler.TraceAnnotation("engine.checkpoint"):
+                self._injector.fire("ckpt.save")
+                self._ckpt.save(self._trainer_step, self._state,
+                                blocking=True)
         except Exception as e:
             # a failed save must never take the trainer down: the last
             # good checkpoint is still on disk and restore skips torn ones
@@ -348,24 +380,27 @@ class ServingEngine:
         ``trainer_crashes`` (its type and message kept as
         ``metrics()["last_trainer_error"]``), and answered with
         :meth:`recover`; the engine keeps serving the published snapshot
-        throughout.
+        throughout.  The call is the profiler span ``engine.train_once``
+        with the step as its ``step`` argument.
         """
-        batch = self._stream(self._trainer_step)
-        if batch is None:
-            return False
-        try:
-            self._injector.fire("trainer.step")
-            self._state = self._train_step(batch)
-            self._trainer_step += 1
-            if self._trainer_step % self.cfg.sync_every == 0:
-                self.publish_from_state()
-            elif self.staleness()["stale"]:
-                self._bump(stale_events=1)
-        except Exception as e:
-            self._note_error("last_trainer_error", e)
-            self._bump(trainer_crashes=1)
-            self.recover()
-        return True
+        with jax.profiler.TraceAnnotation("engine.train_once",
+                                          step=self._trainer_step):
+            batch = self._stream(self._trainer_step)
+            if batch is None:
+                return False
+            try:
+                self._injector.fire("trainer.step")
+                self._state = self._train_step(batch)
+                self._trainer_step += 1
+                if self._trainer_step % self.cfg.sync_every == 0:
+                    self.publish_from_state()
+                elif self.staleness()["stale"]:
+                    self._bump(stale_events=1)
+            except Exception as e:
+                self._note_error("last_trainer_error", e)
+                self._bump(trainer_crashes=1)
+                self.recover()
+            return True
 
     def _train_step(self, batch):
         X, y = batch
@@ -377,16 +412,17 @@ class ServingEngine:
         RE-PUBLISH immediately — a validated snapshot of the restored
         model goes live within one publish, and the normal cadence
         resumes from there (fresh publishes within one sync window)."""
-        if self._ckpt is not None:
-            try:
-                template = jax.eval_shape(lambda: self._state)
-                state, step = self._ckpt.restore_latest(
-                    template, return_step=True)
-                self._state, self._trainer_step = state, step
-            except FileNotFoundError:
-                pass                      # no valid checkpoint: keep memory
-        self._bump(recoveries=1)
-        self.publish_from_state()
+        with jax.profiler.TraceAnnotation("engine.recover"):
+            if self._ckpt is not None:
+                try:
+                    template = jax.eval_shape(lambda: self._state)
+                    state, step = self._ckpt.restore_latest(
+                        template, return_step=True)
+                    self._state, self._trainer_step = state, step
+                except FileNotFoundError:
+                    pass                  # no valid checkpoint: keep memory
+            self._bump(recoveries=1)
+            self.publish_from_state()
 
     # -- admission + serving ----------------------------------------------
 
@@ -396,29 +432,31 @@ class ServingEngine:
         Admission is all-or-nothing per request: if the queue cannot
         hold the WHOLE request under ``max_queue_rows``, the ticket
         resolves ``shed`` immediately and the shed counters advance by
-        exactly this request — the excess is counted, not dropped.
+        exactly this request — the excess is counted, not dropped.  The
+        call is the profiler span ``engine.submit``.
         """
-        X = np.asarray(X, np.float32)
-        assert X.ndim == 2, X.shape
-        t = Ticket(X)
-        with self._q_lock:
-            if self._queued_rows + t.rows > self.cfg.max_queue_rows:
-                admitted = False
+        with jax.profiler.TraceAnnotation("engine.submit"):
+            X = np.asarray(X, np.float32)
+            assert X.ndim == 2, X.shape
+            t = Ticket(X)
+            with self._q_lock:
+                if self._queued_rows + t.rows > self.cfg.max_queue_rows:
+                    admitted = False
+                else:
+                    admitted = True
+                    self._queue.append(t)
+                    self._queued_rows += t.rows
+                    depth = self._queued_rows
+            if admitted:
+                self._bump(admitted_requests=1, admitted_rows=t.rows)
+                with self._m_lock:
+                    if depth > self._metrics["max_queue_rows_seen"]:
+                        self._metrics["max_queue_rows_seen"] = depth
+                self._q_event.set()
             else:
-                admitted = True
-                self._queue.append(t)
-                self._queued_rows += t.rows
-                depth = self._queued_rows
-        if admitted:
-            self._bump(admitted_requests=1, admitted_rows=t.rows)
-            with self._m_lock:
-                if depth > self._metrics["max_queue_rows_seen"]:
-                    self._metrics["max_queue_rows_seen"] = depth
-            self._q_event.set()
-        else:
-            self._bump(shed_requests=1, shed_rows=t.rows)
-            t._resolve("shed")
-        return t
+                self._bump(shed_requests=1, shed_rows=t.rows)
+                t._resolve("shed")
+            return t
 
     @property
     def queued_rows(self) -> int:
@@ -433,31 +471,36 @@ class ServingEngine:
         bucketed, cached jit), and splits the predictions back per
         ticket.  Per-row predictions are independent of batch packing,
         so every ticket's rows are bit-identical to a standalone
-        ``predict_snapshot`` on its pinned version.
+        ``predict_snapshot`` on its pinned version.  The call is the
+        profiler span ``engine.serve_once``, the pop and concatenation in
+        it ``engine.pack``.
         """
-        with self._q_lock:
-            if not self._queue:
-                self._q_event.clear()
-                return 0
-            batch, rows = [], 0
-            while self._queue and (not batch or
-                    rows + self._queue[0].rows <= self.cfg.max_batch_rows):
-                t = self._queue.pop(0)
-                batch.append(t)
-                rows += t.rows
-            self._queued_rows -= rows
-        rec = self._published                   # the one pinned read
-        X = batch[0].X if len(batch) == 1 else \
-            np.concatenate([t.X for t in batch], axis=0)
-        y = np.asarray(sv.predict_snapshot(rec.snap, X,
-                                           backend=self.cfg.backend))
-        off = 0
-        for t in batch:
-            t._resolve("done", y[off:off + t.rows], rec.version)
-            off += t.rows
-        self._bump(served_requests=len(batch), served_rows=rows,
-                   serve_batches=1)
-        return rows
+        with jax.profiler.TraceAnnotation("engine.serve_once"):
+            with jax.profiler.TraceAnnotation("engine.pack"):
+                with self._q_lock:
+                    if not self._queue:
+                        self._q_event.clear()
+                        return 0
+                    batch, rows = [], 0
+                    while self._queue and (not batch or rows
+                                           + self._queue[0].rows
+                                           <= self.cfg.max_batch_rows):
+                        t = self._queue.pop(0)
+                        batch.append(t)
+                        rows += t.rows
+                    self._queued_rows -= rows
+                rec = self._published               # the one pinned read
+                X = batch[0].X if len(batch) == 1 else \
+                    np.concatenate([t.X for t in batch], axis=0)
+            y = np.asarray(sv.predict_snapshot(rec.snap, X,
+                                               backend=self.cfg.backend))
+            off = 0
+            for t in batch:
+                t._resolve("done", y[off:off + t.rows], rec.version)
+                off += t.rows
+            self._bump(served_requests=len(batch), served_rows=rows,
+                       serve_batches=1)
+            return rows
 
     # -- threaded mode -----------------------------------------------------
 

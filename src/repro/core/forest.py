@@ -319,14 +319,16 @@ def _fused_route_stats(cfg: ForestConfig, trees, X, y, w):
     tcfg = cfg.tree
     M = tcfg.max_nodes
     T = trees["feature"].shape[0]
-    leaf = kops.forest_route(trees["feature"], trees["threshold"],
-                             trees["child"], trees["is_leaf"], X,
-                             depth=tcfg.max_depth,
-                             backend=tcfg.split_backend)
-    gl = (jnp.arange(T, dtype=leaf.dtype)[:, None] * M + leaf).reshape(-1)
-    batch_leaf = jax.tree.map(
-        lambda a: a.reshape(T, M),
-        ht._segment_stats(jnp.tile(y, T), gl, T * M, w.reshape(-1)))
+    with jax.named_scope("forest.route"):
+        leaf = kops.forest_route(trees["feature"], trees["threshold"],
+                                 trees["child"], trees["is_leaf"], X,
+                                 depth=tcfg.max_depth,
+                                 backend=tcfg.split_backend)
+        gl = (jnp.arange(T, dtype=leaf.dtype)[:, None] * M
+              + leaf).reshape(-1)
+        batch_leaf = jax.tree.map(
+            lambda a: a.reshape(T, M),
+            ht._segment_stats(jnp.tile(y, T), gl, T * M, w.reshape(-1)))
     return gl, leaf, batch_leaf
 
 
@@ -345,22 +347,23 @@ def _fused_absorb_tables(cfg: ForestConfig, ao_y, ao_sum_x, trees, gl,
     M = tcfg.max_nodes
     T = trees["feature"].shape[0]
     flat = functools.partial(_fold_tables, T=T, M=M)
-    if tcfg.observer_backend == "sketch":
-        # the sketch needs no quantization grid — folded leaf ids alone
-        # segment the batch, so shard deltas stay mergeable by the rank
-        # contract instead of by a shared grid
-        ao_y, ao_sum_x = kops.sketch_update(
-            jax.tree.map(flat, ao_y), flat(ao_sum_x),
-            gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
-            backend=tcfg.split_backend)
-    else:
-        ao_y, ao_sum_x = kops.forest_update(
-            jax.tree.map(flat, ao_y), flat(ao_sum_x),
-            flat(trees["ao_radius"]), flat(trees["ao_origin"]),
-            gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
-            backend=tcfg.split_backend)
-    unflat = lambda a: a.reshape((T, M) + a.shape[1:])
-    return jax.tree.map(unflat, ao_y), unflat(ao_sum_x)
+    with jax.named_scope("forest.absorb"):
+        if tcfg.observer_backend == "sketch":
+            # the sketch needs no quantization grid — folded leaf ids
+            # alone segment the batch, so shard deltas stay mergeable by
+            # the rank contract instead of by a shared grid
+            ao_y, ao_sum_x = kops.sketch_update(
+                jax.tree.map(flat, ao_y), flat(ao_sum_x),
+                gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
+                backend=tcfg.split_backend)
+        else:
+            ao_y, ao_sum_x = kops.forest_update(
+                jax.tree.map(flat, ao_y), flat(ao_sum_x),
+                flat(trees["ao_radius"]), flat(trees["ao_origin"]),
+                gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
+                backend=tcfg.split_backend)
+        unflat = lambda a: a.reshape((T, M) + a.shape[1:])
+        return jax.tree.map(unflat, ao_y), unflat(ao_sum_x)
 
 
 def _fused_member_attempt(cfg: ForestConfig, trees, feat_mask):
@@ -378,26 +381,28 @@ def _fused_member_attempt(cfg: ForestConfig, trees, feat_mask):
     M, F = tcfg.max_nodes, tcfg.n_features
     T = feat_mask.shape[0]
     flat = functools.partial(_fold_tables, T=T, M=M)
-    attempt = jax.vmap(functools.partial(ht.attempt_mask, tcfg))(trees) \
-        & (trees["n_nodes"][:, None] + 1 < M)
+    with jax.named_scope("forest.attempt"):
+        attempt = jax.vmap(functools.partial(ht.attempt_mask, tcfg))(
+            trees) & (trees["n_nodes"][:, None] + 1 < M)
 
-    def do(tr, att):
-        # the folded T*M table axis compacts across trees: the ONE query
-        # gathers only the attempting leaves of the whole ensemble
-        ao_y, ao_sum_x = jax.tree.map(flat, tr["ao_y"]), flat(tr["ao_sum_x"])
-        if tcfg.observer_backend == "sketch":
-            ao_y, ao_sum_x = kops.sketch_to_bins(ao_y, ao_sum_x)  # §2.8
-        merit, thr = kops.forest_best_splits(
-            ao_y, ao_sum_x,
-            flat(tr["ao_radius"]), flat(tr["ao_origin"]),
-            att.reshape(-1), backend=tcfg.split_backend,
-            compact=tcfg.compact_query)
-        return jax.vmap(functools.partial(ht._apply_splits, tcfg))(
-            tr, merit.reshape(T, M, F), thr.reshape(T, M, F), att,
-            feat_mask)
+        def do(tr, att):
+            # the folded T*M table axis compacts across trees: the ONE
+            # query gathers only the attempting leaves of the whole ensemble
+            ao_y = jax.tree.map(flat, tr["ao_y"])
+            ao_sum_x = flat(tr["ao_sum_x"])
+            if tcfg.observer_backend == "sketch":
+                ao_y, ao_sum_x = kops.sketch_to_bins(ao_y, ao_sum_x)  # §2.8
+            merit, thr = kops.forest_best_splits(
+                ao_y, ao_sum_x,
+                flat(tr["ao_radius"]), flat(tr["ao_origin"]),
+                att.reshape(-1), backend=tcfg.split_backend,
+                compact=tcfg.compact_query)
+            return jax.vmap(functools.partial(ht._apply_splits, tcfg))(
+                tr, merit.reshape(T, M, F), thr.reshape(T, M, F), att,
+                feat_mask)
 
-    return jax.lax.cond(attempt.any(), do, lambda tr, a: dict(tr),
-                        trees, attempt)
+        return jax.lax.cond(attempt.any(), do, lambda tr, a: dict(tr),
+                            trees, attempt)
 
 
 def _fused_member_update(cfg: ForestConfig, trees, feat_mask, X, y, w):
@@ -421,10 +426,11 @@ def _fused_member_update(cfg: ForestConfig, trees, feat_mask, X, y, w):
     trees: stacked TreeStates (T leading); w: (T, B) sample weights.
     """
     gl, _, batch_leaf = _fused_route_stats(cfg, trees, X, y, w)
-    trees = dict(trees,
-                 ystats=stats.merge(trees["ystats"], batch_leaf),
-                 seen_since_attempt=trees["seen_since_attempt"]
-                 + batch_leaf["n"])
+    with jax.named_scope("forest.route"):
+        trees = dict(trees,
+                     ystats=stats.merge(trees["ystats"], batch_leaf),
+                     seen_since_attempt=trees["seen_since_attempt"]
+                     + batch_leaf["n"])
     ao_y, ao_sum_x = _fused_absorb_tables(
         cfg, trees["ao_y"], trees["ao_sum_x"], trees, gl, X, y, w)
     trees = dict(trees, ao_y=ao_y, ao_sum_x=ao_sum_x)
@@ -452,26 +458,28 @@ def update(cfg: ForestConfig, state: ForestState, X: jax.Array,
     as the correctness reference); with ``axis_name`` set (inside
     ``shard_map``) only the forest_mse vote reduce communicates.
     """
-    X = jnp.asarray(X, jnp.float32)
-    y = jnp.asarray(y, jnp.float32).reshape(-1)
-    B = y.shape[0]
-    row_w = jnp.ones_like(y) if w is None \
-        else jnp.asarray(w, jnp.float32).reshape(-1)
-    wsum = jnp.maximum(row_w.sum(), 1e-12)
-
     # --- test: prequential member + forest errors on the raw stream ------
-    yhat = member_predictions(cfg, state, X)                   # (T, B)
-    member_mse = (row_w[None, :] * (yhat - y[None, :]) ** 2).sum(1) / wsum
-    fpred = _vote_combine(yhat, state["vote_w"], axis_name)
-    forest_mse = (row_w * (fpred - y) ** 2).sum() / wsum
+    with jax.named_scope("forest.test"):
+        X = jnp.asarray(X, jnp.float32)
+        y = jnp.asarray(y, jnp.float32).reshape(-1)
+        B = y.shape[0]
+        row_w = jnp.ones_like(y) if w is None \
+            else jnp.asarray(w, jnp.float32).reshape(-1)
+        wsum = jnp.maximum(row_w.sum(), 1e-12)
+        yhat = member_predictions(cfg, state, X)               # (T, B)
+        member_mse = (row_w[None, :] * (yhat - y[None, :]) ** 2).sum(1) \
+            / wsum
+        fpred = _vote_combine(yhat, state["vote_w"], axis_name)
+        forest_mse = (row_w * (fpred - y) ** 2).sum() / wsum
 
     # --- train: Poisson(λ) bagging weights, one fused member update ------
-    split = jax.vmap(functools.partial(jax.random.split, num=3))(
-        state["keys"])                                         # (T, 3, 2)
-    keys, wkeys, mkeys = split[:, 0], split[:, 1], split[:, 2]
-    cdf = jnp.asarray(_poisson_cdf(cfg.lam), jnp.float32)
-    w = jax.vmap(lambda k: _poisson_weights(k, cdf, (B,)))(wkeys) \
-        * row_w[None, :]                                       # (T, B)
+    with jax.named_scope("forest.route"):
+        split = jax.vmap(functools.partial(jax.random.split, num=3))(
+            state["keys"])                                     # (T, 3, 2)
+        keys, wkeys, mkeys = split[:, 0], split[:, 1], split[:, 2]
+        cdf = jnp.asarray(_poisson_cdf(cfg.lam), jnp.float32)
+        w = jax.vmap(lambda k: _poisson_weights(k, cdf, (B,)))(wkeys) \
+            * row_w[None, :]                                   # (T, B)
     if cfg.tree.split_backend == "oracle":
         trees = jax.vmap(functools.partial(ht.update, cfg.tree),
                          in_axes=(0, None, None, 0, 0))(
@@ -490,65 +498,72 @@ def update(cfg: ForestConfig, state: ForestState, X: jax.Array,
     # step: a masked tail batch with one live row must not move the EWMA
     # at full drift_alpha (one outlier row could otherwise fire a
     # spurious member swap at stream end).
-    live = row_w.sum() > 0
-    # clamped at 1: importance weights > 1 must not push the EWMA rate
-    # past drift_alpha (alpha > 1 would make the recursion sign-flip)
-    frac = jnp.where(live,
-                     jnp.minimum(wsum / jnp.maximum(jnp.float32(B), 1.0),
-                                 1.0), 0.0)
-    alpha = cfg.drift_alpha * frac
-    first = (state["err_win"]["n"] < 0.5) & live
-    ewma = jnp.where(first, member_mse,
-                     (1.0 - alpha) * state["err_ewma"]
-                     + alpha * member_mse)
-    ref = state["err_win"]
-    sd = jnp.sqrt(jnp.maximum(stats.variance(ref), 1e-12))
-    signal = (ref["n"] >= cfg.drift_min_batches) \
-        & (ewma > ref["mean"] + cfg.drift_kappa * sd)
-    # swap at most the WORST signalling member per batch (per shard when
-    # the tree axis is sharded): staggered resets keep the forest's memory
-    worst = jnp.argmax(jnp.where(signal, ewma, -jnp.inf))
-    drift = signal & (jnp.arange(signal.shape[0]) == worst)
-    # the reference decays by the same real-mass fraction it observes
-    # (decay^frac), so persistently sub-unit weights shift the window's
-    # time constant instead of silently lowering its n equilibrium below
-    # drift_min_batches (which would disarm detection); frac == 1 takes
-    # the exact python constant so unweighted streams are bit-identical
-    decay = jnp.where(frac >= 1.0, cfg.drift_decay,
-                      jnp.float32(cfg.drift_decay) ** frac)
-    decayed = {"n": decay * ref["n"], "mean": ref["mean"],
-               "m2": decay * ref["m2"]}
-    observed = stats.observe(decayed, member_mse, frac)
-    # a signalling member's reference FREEZES (no decay, no observe): if it
-    # wasn't this batch's worst it must keep its clean pre-drift reference
-    # so it can fire again next batch — otherwise the window absorbs the
-    # jump and simultaneous drifts beyond the first are never swapped
-    win = jax.tree.map(
-        lambda o, r: jnp.where(signal, r, o), observed, ref)
+    with jax.named_scope("forest.drift"):
+        live = row_w.sum() > 0
+        # clamped at 1: importance weights > 1 must not push the EWMA rate
+        # past drift_alpha (alpha > 1 would make the recursion sign-flip)
+        frac = jnp.where(live,
+                         jnp.minimum(wsum / jnp.maximum(jnp.float32(B), 1.0),
+                                     1.0), 0.0)
+        alpha = cfg.drift_alpha * frac
+        first = (state["err_win"]["n"] < 0.5) & live
+        ewma = jnp.where(first, member_mse,
+                         (1.0 - alpha) * state["err_ewma"]
+                         + alpha * member_mse)
+        ref = state["err_win"]
+        sd = jnp.sqrt(jnp.maximum(stats.variance(ref), 1e-12))
+        signal = (ref["n"] >= cfg.drift_min_batches) \
+            & (ewma > ref["mean"] + cfg.drift_kappa * sd)
+        # swap at most the WORST signalling member per batch (per shard
+        # when the tree axis is sharded): staggered resets keep the
+        # forest's memory
+        worst = jnp.argmax(jnp.where(signal, ewma, -jnp.inf))
+        drift = signal & (jnp.arange(signal.shape[0]) == worst)
+        # the reference decays by the same real-mass fraction it observes
+        # (decay^frac), so persistently sub-unit weights shift the
+        # window's time constant instead of silently lowering its n
+        # equilibrium below drift_min_batches (which would disarm
+        # detection); frac == 1 takes the exact python constant so
+        # unweighted streams are bit-identical
+        decay = jnp.where(frac >= 1.0, cfg.drift_decay,
+                          jnp.float32(cfg.drift_decay) ** frac)
+        decayed = {"n": decay * ref["n"], "mean": ref["mean"],
+                   "m2": decay * ref["m2"]}
+        observed = stats.observe(decayed, member_mse, frac)
+        # a signalling member's reference FREEZES (no decay, no observe):
+        # if it wasn't this batch's worst it must keep its clean pre-drift
+        # reference so it can fire again next batch — otherwise the window
+        # absorbs the jump and simultaneous drifts beyond the first are
+        # never swapped
+        win = jax.tree.map(
+            lambda o, r: jnp.where(signal, r, o), observed, ref)
 
-    # --- swap: reset drifting members (fresh tree, subspace, window) -----
-    T = drift.shape[0]                   # local shard size under shard_map
-    fresh = jax.tree.map(
-        lambda a: jnp.broadcast_to(a[None], (T,) + a.shape),
-        ht.init_state(cfg.tree))
+        # --- swap: reset drifting members (fresh tree, subspace, window)
+        T = drift.shape[0]               # local shard size under shard_map
+        fresh = jax.tree.map(
+            lambda a: jnp.broadcast_to(a[None], (T,) + a.shape),
+            ht.init_state(cfg.tree))
 
-    def swap(a, f):
-        return jnp.where(drift.reshape((T,) + (1,) * (a.ndim - 1)), f, a)
+        def swap(a, f):
+            return jnp.where(drift.reshape((T,) + (1,) * (a.ndim - 1)),
+                             f, a)
 
-    trees = jax.tree.map(swap, trees, fresh)
-    new_masks = jax.vmap(functools.partial(
-        _draw_mask, F=cfg.tree.n_features, k=cfg.subspace_k()))(mkeys)
-    state = {
-        "trees": trees,
-        "feat_mask": jnp.where(drift[:, None], new_masks, state["feat_mask"]),
-        "keys": keys,
-        "err_win": jax.tree.map(lambda a: jnp.where(drift, 0.0, a), win),
-        "err_ewma": jnp.where(drift, 0.0, ewma),
-        "resets": state["resets"] + drift.astype(jnp.int32),
-    }
-    # vote weights refresh ONCE per learned batch; every read (predict,
-    # the next batch's prequential vote, serve.freeze) reuses them
-    state["vote_w"] = vote_weights(cfg, state)
+        trees = jax.tree.map(swap, trees, fresh)
+        new_masks = jax.vmap(functools.partial(
+            _draw_mask, F=cfg.tree.n_features, k=cfg.subspace_k()))(mkeys)
+        state = {
+            "trees": trees,
+            "feat_mask": jnp.where(drift[:, None], new_masks,
+                                   state["feat_mask"]),
+            "keys": keys,
+            "err_win": jax.tree.map(lambda a: jnp.where(drift, 0.0, a),
+                                    win),
+            "err_ewma": jnp.where(drift, 0.0, ewma),
+            "resets": state["resets"] + drift.astype(jnp.int32),
+        }
+        # vote weights refresh ONCE per learned batch; every read (predict,
+        # the next batch's prequential vote, serve.freeze) reuses them
+        state["vote_w"] = vote_weights(cfg, state)
     return state, {"member_mse": member_mse, "forest_mse": forest_mse,
                    "drift": drift}
 

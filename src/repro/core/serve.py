@@ -36,6 +36,7 @@ tree-axis training shard.
 from __future__ import annotations
 
 import functools
+import time
 from dataclasses import dataclass
 
 import jax
@@ -108,47 +109,49 @@ def validate_snapshot(snap: Snapshot) -> Snapshot:
 
     A host-side O(T·Mr) numpy pass — called once per freeze/publish,
     never on the per-request path.  Returns ``snap`` unchanged so
-    callers can gate inline: ``publish(validate_snapshot(s))``.
+    callers can gate inline: ``publish(validate_snapshot(s))``.  Each
+    call is one ``serve.validate`` profiler span.
     """
-    feat = np.asarray(snap.feature)
-    thr = np.asarray(snap.threshold)
-    child = np.asarray(snap.child)
-    is_leaf = np.asarray(snap.is_leaf)
-    mean = np.asarray(snap.leaf_mean)
-    vote_w = np.asarray(snap.vote_w)
-    T, Mr = feat.shape
+    with jax.profiler.TraceAnnotation("serve.validate"):
+        feat = np.asarray(snap.feature)
+        thr = np.asarray(snap.threshold)
+        child = np.asarray(snap.child)
+        is_leaf = np.asarray(snap.is_leaf)
+        mean = np.asarray(snap.leaf_mean)
+        vote_w = np.asarray(snap.vote_w)
+        T, Mr = feat.shape
 
-    def bad(msg):
-        raise SnapshotValidationError(
-            f"snapshot v{int(np.asarray(snap.version))} "
-            f"(step {int(np.asarray(snap.step))}): {msg}")
+        def bad(msg):
+            raise SnapshotValidationError(
+                f"snapshot v{int(np.asarray(snap.version))} "
+                f"(step {int(np.asarray(snap.step))}): {msg}")
 
-    if not (np.isfinite(vote_w).all() and (vote_w >= 0).all()):
-        bad("vote weights must be finite and non-negative")
-    if not np.isfinite(mean).all():
-        bad("leaf means must be finite")
-    if int(np.asarray(snap.version)) < 0 or int(np.asarray(snap.step)) < 0:
-        bad("version/step stamps must be non-negative")
-    for t in range(T):
-        internal = ~is_leaf[t]
-        if not np.isfinite(thr[t][internal]).all():
-            bad(f"tree {t}: non-finite threshold on an internal node")
-        if internal.any() and (feat[t][internal] < 0).any():
-            bad(f"tree {t}: negative feature id on an internal node")
-        ch = child[t][internal]                       # (n_internal, 2)
-        if (child[t][~internal] != -1).any():
-            bad(f"tree {t}: leaf rows must carry -1 children")
-        if internal.any():
-            if ch.min() < 0 or ch.max() >= Mr:
-                bad(f"tree {t}: child id out of range [0, {Mr})")
-            parents = np.nonzero(internal)[0]
-            if (ch <= parents[:, None]).any():
-                bad(f"tree {t}: child id <= parent id breaks the BFS "
-                    f"level-order contract")
-            flat = ch.reshape(-1)
-            if len(np.unique(flat)) != len(flat) or (flat == 0).any():
-                bad(f"tree {t}: a node is claimed by two parents (or the "
-                    f"root is a child)")
+        if not (np.isfinite(vote_w).all() and (vote_w >= 0).all()):
+            bad("vote weights must be finite and non-negative")
+        if not np.isfinite(mean).all():
+            bad("leaf means must be finite")
+        if int(np.asarray(snap.version)) < 0 or int(np.asarray(snap.step)) < 0:
+            bad("version/step stamps must be non-negative")
+        for t in range(T):
+            internal = ~is_leaf[t]
+            if not np.isfinite(thr[t][internal]).all():
+                bad(f"tree {t}: non-finite threshold on an internal node")
+            if internal.any() and (feat[t][internal] < 0).any():
+                bad(f"tree {t}: negative feature id on an internal node")
+            ch = child[t][internal]                       # (n_internal, 2)
+            if (child[t][~internal] != -1).any():
+                bad(f"tree {t}: leaf rows must carry -1 children")
+            if internal.any():
+                if ch.min() < 0 or ch.max() >= Mr:
+                    bad(f"tree {t}: child id out of range [0, {Mr})")
+                parents = np.nonzero(internal)[0]
+                if (ch <= parents[:, None]).any():
+                    bad(f"tree {t}: child id <= parent id breaks the BFS "
+                        f"level-order contract")
+                flat = ch.reshape(-1)
+                if len(np.unique(flat)) != len(flat) or (flat == 0).any():
+                    bad(f"tree {t}: a node is claimed by two parents (or the "
+                        f"root is a child)")
     return snap
 
 
@@ -186,7 +189,8 @@ def _bfs_reindex(feature, threshold, child, is_leaf, mean, Mr: int):
     return f, thr, ch, lf, mu, (max(node_depth) if n else 0)
 
 
-def freeze(state, *, version: int = 0, step: int = 0) -> Snapshot:
+def freeze(state, *, version: int = 0, step: int = 0,
+           timings: dict | None = None) -> Snapshot:
     """Pack a trained tree or forest state into a serving Snapshot.
 
     ``state``: a :func:`repro.core.hoeffding.init_state` pytree (single
@@ -203,33 +207,51 @@ def freeze(state, *, version: int = 0, step: int = 0) -> Snapshot:
     before returning, so a snapshot that ever reaches a serving engine
     is structurally valid by construction; the engine's publish path
     re-validates after its fault-injection hooks (the rollback gate).
+
+    The packing is the profiler span ``serve.freeze``, with the children
+    ``serve.freeze.fetch`` (the device-to-host reads, which first wait
+    for every step still queued on the device), ``serve.freeze.reindex``
+    and ``serve.freeze.upload``; the validation after it is its own
+    ``serve.validate`` span.  ``timings``: a dict that, when given,
+    receives the seconds of those three children under ``"fetch"``,
+    ``"reindex"`` and ``"upload"``.
     """
     if "trees" in state:
         trees, vote_w, single = state["trees"], state["vote_w"], False
     else:
         trees = jax.tree.map(lambda a: a[None], state)
         vote_w, single = jnp.ones((1,), jnp.float32), True
-    feat = np.asarray(trees["feature"])
-    thr = np.asarray(trees["threshold"])
-    child = np.asarray(trees["child"])
-    is_leaf = np.asarray(trees["is_leaf"])
-    mean = np.asarray(trees["ystats"]["mean"])
-    n_nodes = np.asarray(trees["n_nodes"])
-    T = feat.shape[0]
-
-    Mr = 8
-    while Mr < int(n_nodes.max()):
-        Mr *= 2
-    packed = [_bfs_reindex(feat[t], thr[t], child[t], is_leaf[t], mean[t], Mr)
-              for t in range(T)]
-    stack = lambda i: jnp.asarray(np.stack([p[i] for p in packed]))
-    return validate_snapshot(Snapshot(
-        feature=stack(0), threshold=stack(1), child=stack(2),
-        is_leaf=stack(3), leaf_mean=stack(4),
-        vote_w=jnp.asarray(vote_w, jnp.float32),
-        depth=max(p[5] for p in packed), single=single,
-        version=jnp.asarray(version, jnp.int32),
-        step=jnp.asarray(step, jnp.int32)))
+    t0 = time.perf_counter()
+    with jax.profiler.TraceAnnotation("serve.freeze"):
+        with jax.profiler.TraceAnnotation("serve.freeze.fetch"):
+            feat = np.asarray(trees["feature"])
+            thr = np.asarray(trees["threshold"])
+            child = np.asarray(trees["child"])
+            is_leaf = np.asarray(trees["is_leaf"])
+            mean = np.asarray(trees["ystats"]["mean"])
+            n_nodes = np.asarray(trees["n_nodes"])
+        t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.freeze.reindex"):
+            Mr = 8
+            while Mr < int(n_nodes.max()):
+                Mr *= 2
+            packed = [_bfs_reindex(feat[t], thr[t], child[t], is_leaf[t],
+                                   mean[t], Mr)
+                      for t in range(feat.shape[0])]
+        t2 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("serve.freeze.upload"):
+            stack = lambda i: jnp.asarray(np.stack([p[i] for p in packed]))
+            snap = Snapshot(
+                feature=stack(0), threshold=stack(1), child=stack(2),
+                is_leaf=stack(3), leaf_mean=stack(4),
+                vote_w=jnp.asarray(vote_w, jnp.float32),
+                depth=max(p[5] for p in packed), single=single,
+                version=jnp.asarray(version, jnp.int32),
+                step=jnp.asarray(step, jnp.int32))
+        t3 = time.perf_counter()
+    if timings is not None:
+        timings.update(fetch=t1 - t0, reindex=t2 - t1, upload=t3 - t2)
+    return validate_snapshot(snap)
 
 
 def _predict_impl(feature, threshold, child, is_leaf, leaf_mean, vote_w, X,
@@ -275,25 +297,27 @@ def predict_snapshot(snap: Snapshot, X, *,
     together.  Only an engine-owned buffer is ever donated: the padded
     copy when padding happened, else (TPU only) a defensive device copy
     of X — the caller's array is never consumed out from under a later
-    reuse.  Under an enclosing trace the body inlines.
+    reuse.  Under an enclosing trace the body inlines.  Each call is one
+    ``serve.predict`` profiler span.
     """
-    backend = kops.resolve_backend(backend)
-    X = jnp.asarray(X, jnp.float32)
-    tabs = (snap.feature, snap.threshold, snap.child, snap.is_leaf,
-            snap.leaf_mean, snap.vote_w)
-    if kops._is_traced(*tabs, X):
-        return _predict_impl(*tabs, X, plies=snap.depth, backend=backend,
-                             single=snap.single)
-    T, Mr = snap.feature.shape
-    p = kops.tuned("forest_route", backend,
-                   kops._shape_class_route(T, Mr, int(X.shape[1])))
-    X, B, padded = kops.pad_rows(X, 128, p["batch_ladder"])
-    if not padded and jax.default_backend() == "tpu":
-        X = jnp.copy(X)     # donate our copy, not the caller's buffer
-    out = _jit_predict(backend, kops.depth_bucket(snap.depth,
-                                                  p["ply_round"]),
-                       snap.single)(*tabs, X)
-    return out[:B] if padded else out
+    with jax.profiler.TraceAnnotation("serve.predict"):
+        backend = kops.resolve_backend(backend)
+        X = jnp.asarray(X, jnp.float32)
+        tabs = (snap.feature, snap.threshold, snap.child, snap.is_leaf,
+                snap.leaf_mean, snap.vote_w)
+        if kops._is_traced(*tabs, X):
+            return _predict_impl(*tabs, X, plies=snap.depth, backend=backend,
+                                 single=snap.single)
+        T, Mr = snap.feature.shape
+        p = kops.tuned("forest_route", backend,
+                       kops._shape_class_route(T, Mr, int(X.shape[1])))
+        X, B, padded = kops.pad_rows(X, 128, p["batch_ladder"])
+        if not padded and jax.default_backend() == "tpu":
+            X = jnp.copy(X)     # donate our copy, not the caller's buffer
+        out = _jit_predict(backend, kops.depth_bucket(snap.depth,
+                                                      p["ply_round"]),
+                           snap.single)(*tabs, X)
+        return out[:B] if padded else out
 
 
 def clear_jit_caches() -> None:

@@ -13,12 +13,14 @@ is ONE program over a leading tree axis:
 * **random subspaces** — each member draws a feature mask of
   ``max(1, round(subspace * F))`` features; masked features still fill
   their QO tables but can never win a split (ARF-style decorrelation);
-* **fused execution** — the T member updates run as ONE pass: the tree
-  axis folds into the table axis of the PR-1 ``forest_update`` /
-  ``forest_best_splits`` pipeline (global leaf ids ``t*M + leaf``), so
-  absorb and the split query are each a single kernel/XLA call for the
-  whole ensemble and only the cheap per-tree decision/scatter stage is
-  vmapped (:func:`_fused_member_update`);
+* **fused execution** — the T member updates run as ONE pass: absorb is
+  one ``forest_update`` call whose groups are the members (its grid
+  walks member by member, so a member's rows meet only its own tables),
+  and the tree axis folds into the table axis of ``forest_best_splits``
+  (global leaf ids ``t*M + leaf``), so absorb and the split query are
+  each a single kernel/XLA call for the whole ensemble and only the
+  cheap per-tree decision/scatter stage is vmapped
+  (:func:`_fused_member_update`);
 * **tree-axis sharding** — every leaf of the forest state carries the
   tree axis first, so :func:`repro.train.sharding.forest_state_specs`
   spreads T trees across the device mesh with ``shard_map``; members
@@ -305,16 +307,21 @@ def _fold_tables(a, T, M):
     return a.reshape((T * M,) + a.shape[2:])
 
 
+def _global_leaf(leaf, M):
+    """(T, B) per-member leaf ids -> (T*B,) folded ids ``t*M + leaf``."""
+    T = leaf.shape[0]
+    return (jnp.arange(T, dtype=leaf.dtype)[:, None] * M + leaf).reshape(-1)
+
+
 def _fused_route_stats(cfg: ForestConfig, trees, X, y, w):
     """Route all T members and reduce the batch's per-leaf target stats.
 
     ONE fused route for all T trees (the §2.6 folded-node-axis sweep) and
     one flat segment reduction over global leaf ids ``t*M + leaf``.
-    Returns ``(gl, leaf, batch_leaf)``: the (T*B,) folded leaf ids, the
-    unfolded (T, B) per-tree leaf ids, and the batch's (T, M) Stats —
-    the shard-local monitor quantities of the §4.1 data-parallel
-    protocol (which accumulates them in a delta instead of folding them
-    straight into ``trees``).
+    Returns ``(leaf, batch_leaf)``: the (T, B) per-tree leaf ids and the
+    batch's (T, M) Stats — the shard-local monitor quantities of the
+    §4.1 data-parallel protocol (which accumulates them in a delta
+    instead of folding them straight into ``trees``).
     """
     tcfg = cfg.tree
     M = tcfg.max_nodes
@@ -324,15 +331,14 @@ def _fused_route_stats(cfg: ForestConfig, trees, X, y, w):
                                  trees["child"], trees["is_leaf"], X,
                                  depth=tcfg.max_depth,
                                  backend=tcfg.split_backend)
-        gl = (jnp.arange(T, dtype=leaf.dtype)[:, None] * M
-              + leaf).reshape(-1)
         batch_leaf = jax.tree.map(
             lambda a: a.reshape(T, M),
-            ht._segment_stats(jnp.tile(y, T), gl, T * M, w.reshape(-1)))
-    return gl, leaf, batch_leaf
+            ht._segment_stats(jnp.tile(y, T), _global_leaf(leaf, M), T * M,
+                              w.reshape(-1)))
+    return leaf, batch_leaf
 
 
-def _fused_absorb_tables(cfg: ForestConfig, ao_y, ao_sum_x, trees, gl,
+def _fused_absorb_tables(cfg: ForestConfig, ao_y, ao_sum_x, trees, leaf,
                          X, y, w):
     """Absorb a routed batch into ANY (T, M, F, C) table set in one pass.
 
@@ -340,30 +346,29 @@ def _fused_absorb_tables(cfg: ForestConfig, ao_y, ao_sum_x, trees, gl,
     ``trees["ao_*"]`` tables, or a shard-local DELTA starting from
     zero — §4.1); the quantization grid (radius/origin) always comes
     from ``trees``, so every shard bins identically, which is what makes
-    the deltas mergeable.  ``gl``: (T*B,) folded leaf ids from
-    :func:`_fused_route_stats`; w: (T, B).  Returns the merged tables.
+    the deltas mergeable.  ``leaf``: (T, B) per-tree leaf ids from
+    :func:`_fused_route_stats`; w: (T, B).  The members are the groups
+    of ONE ``forest_update`` call (§5.1): X and y are shared, and each
+    member's rows meet only its own tables.  Returns the merged tables.
     """
     tcfg = cfg.tree
     M = tcfg.max_nodes
     T = trees["feature"].shape[0]
-    flat = functools.partial(_fold_tables, T=T, M=M)
     with jax.named_scope("forest.absorb"):
         if tcfg.observer_backend == "sketch":
             # the sketch needs no quantization grid — folded leaf ids
             # alone segment the batch, so shard deltas stay mergeable by
             # the rank contract instead of by a shared grid
+            flat = functools.partial(_fold_tables, T=T, M=M)
             ao_y, ao_sum_x = kops.sketch_update(
                 jax.tree.map(flat, ao_y), flat(ao_sum_x),
-                gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
-                backend=tcfg.split_backend)
-        else:
-            ao_y, ao_sum_x = kops.forest_update(
-                jax.tree.map(flat, ao_y), flat(ao_sum_x),
-                flat(trees["ao_radius"]), flat(trees["ao_origin"]),
-                gl, jnp.tile(X, (T, 1)), jnp.tile(y, T), w.reshape(-1),
-                backend=tcfg.split_backend)
-        unflat = lambda a: a.reshape((T, M) + a.shape[1:])
-        return jax.tree.map(unflat, ao_y), unflat(ao_sum_x)
+                _global_leaf(leaf, M), jnp.tile(X, (T, 1)), jnp.tile(y, T),
+                w.reshape(-1), backend=tcfg.split_backend)
+            unflat = lambda a: a.reshape((T, M) + a.shape[1:])
+            return jax.tree.map(unflat, ao_y), unflat(ao_sum_x)
+        return kops.forest_update(
+            ao_y, ao_sum_x, trees["ao_radius"], trees["ao_origin"],
+            leaf, X, y, w, backend=tcfg.split_backend)
 
 
 def _fused_member_attempt(cfg: ForestConfig, trees, feat_mask):
@@ -414,7 +419,9 @@ def _fused_member_update(cfg: ForestConfig, trees, feat_mask, X, y, w):
     the tree axis is folded into the table axis the kernels already
     batch over: T trees x M nodes become one (T*M, F, C) forest with
     global leaf ids ``t*M + leaf``, so absorb is ONE
-    :func:`repro.kernels.ops.forest_update`, the split query ONE
+    :func:`repro.kernels.ops.forest_update` (its group form, one group
+    per member, so the grid skips every cross-member pair), the split
+    query ONE
     :func:`repro.kernels.ops.forest_best_splits` (both tree-count
     agnostic on every backend), and only the cheap O(M) decision/scatter
     stage (:func:`repro.core.hoeffding._apply_splits`) is vmapped.
@@ -425,14 +432,14 @@ def _fused_member_update(cfg: ForestConfig, trees, feat_mask, X, y, w):
 
     trees: stacked TreeStates (T leading); w: (T, B) sample weights.
     """
-    gl, _, batch_leaf = _fused_route_stats(cfg, trees, X, y, w)
+    leaf, batch_leaf = _fused_route_stats(cfg, trees, X, y, w)
     with jax.named_scope("forest.route"):
         trees = dict(trees,
                      ystats=stats.merge(trees["ystats"], batch_leaf),
                      seen_since_attempt=trees["seen_since_attempt"]
                      + batch_leaf["n"])
     ao_y, ao_sum_x = _fused_absorb_tables(
-        cfg, trees["ao_y"], trees["ao_sum_x"], trees, gl, X, y, w)
+        cfg, trees["ao_y"], trees["ao_sum_x"], trees, leaf, X, y, w)
     trees = dict(trees, ao_y=ao_y, ao_sum_x=ao_sum_x)
     return _fused_member_attempt(cfg, trees, feat_mask)
 

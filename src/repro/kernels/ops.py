@@ -410,33 +410,50 @@ def _forest_update_jnp(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w):
 
 
 def _pad_batch(leaf, X, y, w, tile_b):
+    """Pad the batch axis (the last of ``leaf``/``w``, the first of X/y)
+    to a multiple of ``tile_b`` with leaf = -1, w = 0 rows."""
     B, F = X.shape
     Bp = round_up(max(B, tile_b), tile_b)
     pad = Bp - B
     if pad:
-        leaf = jnp.concatenate([leaf, jnp.full((pad,), -1, leaf.dtype)])
+        fill = lambda a, v: jnp.concatenate(
+            [a, jnp.full(a.shape[:-1] + (pad,), v, a.dtype)], -1)
+        leaf = fill(leaf, -1)
         X = jnp.concatenate([X, jnp.zeros((pad, F), X.dtype)])
         y = jnp.concatenate([y, jnp.zeros((pad,), y.dtype)])
-        w = jnp.concatenate([w, jnp.zeros((pad,), w.dtype)])
+        w = fill(w, 0)
     return leaf, X, y, w
 
 
 def _forest_update_impl(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w,
                         *, backend: str, tile_b: int, tile_m: int):
-    """Backend dispatch body of :func:`forest_update` (inputs normalized)."""
+    """Backend dispatch body of :func:`forest_update` (inputs normalized
+    to the group form: tables (G, M, F, C), leaf/w (G, B))."""
+    G, M, F, C = ao_sum_x.shape
+    fold = lambda a: a.reshape((G * M,) + a.shape[2:])
+    unfold = lambda a: a.reshape((G, M) + a.shape[1:])
     if backend == "jnp":
-        return _forest_update_jnp(ao_y, ao_sum_x, ao_radius, ao_origin,
-                                  leaf, X, y, w)
+        # the fused lowering folds the groups into one table axis; a pad
+        # row (leaf = -1) must not fold into the previous group's tables
+        gl = jnp.where(leaf >= 0, jnp.arange(G, dtype=leaf.dtype)[:, None] * M
+                       + leaf, -1).reshape(-1)
+        ao_y, ao_sum_x = _forest_update_jnp(
+            jax.tree.map(fold, ao_y), fold(ao_sum_x), fold(ao_radius),
+            fold(ao_origin), gl, jnp.tile(X, (G, 1)), jnp.tile(y, G),
+            w.reshape(-1))
+        return jax.tree.map(unfold, ao_y), unfold(ao_sum_x)
 
-    M, F, C = ao_sum_x.shape
     tile_m = min(tile_m, round_up(M, 8))
     tile_b = min(tile_b, round_up(X.shape[0], 128))
     leaf, X, y, w = _pad_batch(leaf, X, y, w, tile_b)
-    dense = pack_forest(ao_y, ao_sum_x, ao_radius, ao_origin, tile_m=tile_m)
+    dense = pack_forest(jax.tree.map(fold, ao_y), fold(ao_sum_x),
+                        fold(ao_radius), fold(ao_origin), tile_m=tile_m,
+                        groups=G)
     dense = qo_update_leaves_pallas(
-        dense, leaf[None, :], X.T[:, None, :], y[None, :], w[None, :],
+        dense, leaf[:, None, :], X.T[:, None, :], y[None, :], w[:, None, :],
         n_bins=C, tile_b=tile_b, tile_m=tile_m, interpret=_kernel_interpret(backend))
-    return unpack_forest(dense, M, C)
+    ao_y, ao_sum_x = unpack_forest(dense, M, C, groups=G)
+    return jax.tree.map(unfold, ao_y), unfold(ao_sum_x)
 
 
 def _jit_forest_update(backend: str, tile_b: int, tile_m: int):
@@ -459,6 +476,15 @@ def forest_update(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w=None, *,
     property-tested in tests/test_weighted.py).
     Returns the merged (ao_y, ao_sum_x).
 
+    Group form: tables with a leading group axis — (G, M, F, C) and
+    (G, M, F) — take leaf: (G, B) group-local ids and w: (G, B), with X
+    and y shared by every group (a forest's members, each routing the
+    same batch; DESIGN.md §5.1).  Group g's rows land only in group g's
+    tables, and the kernel's grid walks group by group, so no work is
+    spent pairing one group's rows with another's tables.  Returns
+    (G, M, F, C) tables.  The group count is read from the tables' rank;
+    one group is exactly the ungrouped call.
+
     ``tile_b``/``tile_m`` (None: tuned, defaults 256/128) are schedule
     knobs; pad rows carry leaf = -1, w = 0 and vanish on every backend.
     ``tile_m`` (table-axis grid) and the batch ladder are bit-identical
@@ -471,21 +497,28 @@ def forest_update(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w=None, *,
     caller's jit fuses the whole absorb stage.
     """
     backend = resolve_backend(backend)
-    leaf = jnp.asarray(leaf, jnp.int32).reshape(-1)
+    grouped = ao_sum_x.ndim == 4
+    if not grouped:
+        ao_y, ao_sum_x, ao_radius, ao_origin = jax.tree.map(
+            lambda a: a[None], (ao_y, ao_sum_x, ao_radius, ao_origin))
+    G, M, F, C = ao_sum_x.shape
     X = jnp.asarray(X, jnp.float32)
     y = jnp.asarray(y, jnp.float32).reshape(-1)
-    w = jnp.ones_like(y) if w is None else jnp.asarray(w, jnp.float32).reshape(-1)
-    M, F, C = ao_sum_x.shape
-    p = tuned("forest_update", backend, _shape_class_tables(M, F, C),
+    leaf = jnp.asarray(leaf, jnp.int32).reshape(G, -1)
+    w = jnp.ones(leaf.shape, jnp.float32) if w is None else \
+        jnp.broadcast_to(jnp.asarray(w, jnp.float32), leaf.shape)
+    p = tuned("forest_update", backend, _shape_class_tables(G * M, F, C),
               tile_b=tile_b, tile_m=tile_m)
     if _is_traced(ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w):
-        return _forest_update_impl(ao_y, ao_sum_x, ao_radius, ao_origin,
-                                   leaf, X, y, w, backend=backend,
-                                   tile_b=p["tile_b"], tile_m=p["tile_m"])
-    leaf, X, y, w = _pad_batch(
-        leaf, X, y, w, _ladder_bucket(X.shape[0], 128, p["batch_ladder"]))
-    return _jit_forest_update(backend, p["tile_b"], p["tile_m"])(
-        ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w)
+        out = _forest_update_impl(ao_y, ao_sum_x, ao_radius, ao_origin,
+                                  leaf, X, y, w, backend=backend,
+                                  tile_b=p["tile_b"], tile_m=p["tile_m"])
+    else:
+        leaf, X, y, w = _pad_batch(
+            leaf, X, y, w, _ladder_bucket(X.shape[0], 128, p["batch_ladder"]))
+        out = _jit_forest_update(backend, p["tile_b"], p["tile_m"])(
+            ao_y, ao_sum_x, ao_radius, ao_origin, leaf, X, y, w)
+    return out if grouped else jax.tree.map(lambda a: a[0], out)
 
 
 def _forest_merge_impl(a_y, a_sum_x, b_y, b_sum_x, *, backend: str,

@@ -7,10 +7,16 @@ leaves and must fold each row into F per-feature QO tables of its leaf —
 ``segment_sum`` scatters over a flat ``M*F*C`` id space; here the whole
 absorb stage is one ``pallas_call`` with a
 
-    grid = (F, leaf-tiles, batch-tiles)
+    grid = (F, G, leaf-tiles per group, batch-tiles)
 
-so each grid step owns a (tile_m, Cp) slab of tables for one feature and
-streams a (tile_b,) slice of the batch through the MXU:
+over G groups of M tables each (a forest's members, DESIGN.md §5.1; one
+group for a single tree).  The batch is shared by every group, but each
+group routes it on its own: group g's leaf ids and weights are row g of
+``leaf``/``w``, and its rows can only land in its own tables, so the grid
+pairs group g's leaf tiles with group g's ids alone — a row never meets
+another group's tables, and no grid step is spent on pairs that cannot
+hold a row.  Each grid step owns a (tile_m, Cp) slab of tables for one
+feature and streams a (tile_b,) slice of the batch through the MXU:
 
     onehot_leaf : (T, tile_m)   row t -> local leaf slot (0 outside tile)
     onehot_bin  : (T, Cp)       row t -> quantized bin of x[t, f]
@@ -22,21 +28,27 @@ The per-(leaf, bin) tile M2 uses the two-pass residual form: the tile bin
 means are gathered back per row with one more MXU matvec and squared
 residuals are contracted exactly like the sums — no naive `sum y^2`
 cancellation (paper §3).  Tile statistics merge into the running table
-with the Chan operator (Eqs. 4-5) kept in VMEM across the (sequential)
-batch-tile grid dimension, so each table slab does one HBM round-trip per
-call regardless of B.
+with the Chan operator (Eqs. 4-5) kept in VMEM across the (sequential,
+innermost) batch-tile grid dimension, so each table slab does one HBM
+round-trip per call regardless of B.
 
 Dense forest layout (lane dim Cp = C rounded up to 128):
 
-    tables : (F, 8, Mp, Cp) f32
+    tables : (F, 8, G*Mp_g, Cp) f32
       row 0: n        row 1: mean     row 2: M2      row 3: sum_x
       row 4: radius   row 5: origin   (broadcast along lanes)
       row 6: attempt mask (query kernel only)        row 7: padding
 
-Routed leaf ids ride along as an int32 ``(1, Bp)`` vector; rows whose leaf
-falls outside the current leaf tile contribute nothing (their one-hot leaf
-row is all zero), which also makes batch padding (leaf id = -1, w = 0)
-free.  No ``(B*F,)`` segment-id array is ever materialized.
+Each group's M tables are padded to Mp_g (a multiple of tile_m) on their
+own, so leaf tile j of group g is table block ``g*(Mp_g/tile_m) + j``
+and no tile straddles two groups (M = 1,023 pads to Mp_g = 1,024).
+
+Routed leaf ids are group-local (0..M-1) and ride along as int32
+``(G, 1, Bp)``, the weights as ``(G, 1, Bp)``; x ``(F, 1, Bp)`` and y
+``(1, Bp)`` are read by every group.  Rows whose leaf falls outside the
+current leaf tile contribute nothing (their one-hot leaf row is all
+zero), which also makes batch padding (leaf id = -1, w = 0) free.  No
+``(B*F,)`` segment-id array is ever materialized.
 """
 from __future__ import annotations
 
@@ -64,34 +76,48 @@ def round_up(n: int, m: int) -> int:
 
 
 def pack_forest(ao_y, ao_sum_x, ao_radius, ao_origin, attempt=None,
-                *, tile_m: int = 128) -> jax.Array:
-    """(M, F, C) dict-of-arrays state -> dense (F, 8, Mp, Cp) forest."""
-    M, F, C = ao_sum_x.shape
+                *, tile_m: int = 128, groups: int = 1) -> jax.Array:
+    """(G*M, F, C) dict-of-arrays state -> dense (F, 8, G*Mp_g, Cp) forest.
+
+    The table axis holds ``groups`` blocks of M tables; each block is
+    padded to Mp_g on its own (module docstring)."""
+    N, F, C = ao_sum_x.shape
+    M = N // groups
     Mp = round_up(M, min(tile_m, round_up(M, 8)))
     Cp = round_up(C, 128)
-    dense = jnp.zeros((F, FOREST_ROWS, Mp, Cp), jnp.float32)
+    dense = jnp.zeros((F, FOREST_ROWS, groups * Mp, Cp), jnp.float32)
 
-    def put(row, arr):  # arr: (M, F, C)
-        return dense.at[:, row, :M, :C].set(jnp.transpose(arr, (1, 0, 2)))
+    def spread(arr):  # (G*M, ...) -> (G*Mp, ...), zero rows after each group
+        a = arr.reshape((groups, M) + arr.shape[1:])
+        a = jnp.pad(a, [(0, 0), (0, Mp - M)] + [(0, 0)] * (a.ndim - 2))
+        return a.reshape((groups * Mp,) + arr.shape[1:])
+
+    def put(row, arr):  # arr: (G*M, F, C)
+        return dense.at[:, row, :, :C].set(jnp.transpose(spread(arr), (1, 0, 2)))
 
     dense = put(ROW_N, ao_y["n"])
     dense = put(ROW_MEAN, ao_y["mean"])
     dense = put(ROW_M2, ao_y["m2"])
     dense = put(ROW_SUMX, ao_sum_x)
     # per-(leaf, feature) scalars broadcast along the lane dim
-    dense = dense.at[:, ROW_RADIUS, :M, :].set(ao_radius.T[:, :, None])
-    dense = dense.at[:, ROW_ORIGIN, :M, :].set(ao_origin.T[:, :, None])
+    dense = dense.at[:, ROW_RADIUS].set(spread(ao_radius).T[:, :, None])
+    dense = dense.at[:, ROW_ORIGIN].set(spread(ao_origin).T[:, :, None])
     if attempt is not None:
-        att = attempt.astype(jnp.float32)[None, :, None]          # (1, M, 1)
-        dense = dense.at[:, ROW_ATTEMPT, :M, :].set(jnp.broadcast_to(
-            att, (F, M, Cp)))
+        att = spread(attempt.astype(jnp.float32))[None, :, None]
+        dense = dense.at[:, ROW_ATTEMPT].set(jnp.broadcast_to(
+            att, (F, groups * Mp, Cp)))
     return dense
 
 
-def unpack_forest(dense: jax.Array, M: int, C: int):
-    """Dense (F, 8, Mp, Cp) -> (ao_y dict, ao_sum_x), shapes (M, F, C)."""
+def unpack_forest(dense: jax.Array, M: int, C: int, groups: int = 1):
+    """Dense (F, 8, G*Mp_g, Cp) -> (ao_y dict, ao_sum_x), shapes (G*M, F, C);
+    ``M`` is the table count of one group."""
+    F, _, GMp, _ = dense.shape
+    Mp = GMp // groups
+
     def get(row):
-        return jnp.transpose(dense[:, row, :M, :C], (1, 0, 2))
+        a = dense[:, row, :, :C].reshape(F, groups, Mp, C)[:, :, :M]
+        return jnp.transpose(a, (1, 2, 0, 3)).reshape(groups * M, F, C)
 
     ao_y = {"n": get(ROW_N), "mean": get(ROW_MEAN), "m2": get(ROW_M2)}
     return ao_y, get(ROW_SUMX)
@@ -99,8 +125,8 @@ def unpack_forest(dense: jax.Array, M: int, C: int):
 
 def _qo_update_leaves_kernel(leaf_ref, x_ref, y_ref, w_ref, tab_ref, out_ref,
                              *, n_bins: int, tile_m: int):
-    j = pl.program_id(1)          # leaf tile
-    i = pl.program_id(2)          # batch tile (innermost: VMEM accumulation)
+    j = pl.program_id(2)          # leaf tile within the group
+    i = pl.program_id(3)          # batch tile (innermost: VMEM accumulation)
 
     @pl.when(i == 0)
     def _seed():
@@ -110,8 +136,8 @@ def _qo_update_leaves_kernel(leaf_ref, x_ref, y_ref, w_ref, tab_ref, out_ref,
     T = x_ref.shape[2]
     x = x_ref[0, 0, :]
     yv = y_ref[0, :]
-    w = w_ref[0, :]
-    leaf = leaf_ref[0, :]
+    w = w_ref[0, 0, :]
+    leaf = leaf_ref[0, 0, :]
 
     # one-hot over the local leaf slots; rows outside this tile are all-zero
     lloc = leaf - j * tile_m
@@ -174,32 +200,34 @@ def qo_update_leaves_pallas(tab: jax.Array, leaf: jax.Array, x: jax.Array,
                             y: jax.Array, w: jax.Array, *, n_bins: int,
                             tile_b: int = 256, tile_m: int = 128,
                             interpret: bool = False) -> jax.Array:
-    """tab: (F, 8, Mp, Cp); leaf: (1, Bp) i32; x: (F, 1, Bp); y/w: (1, Bp).
+    """tab: (F, 8, G*Mp_g, Cp); leaf: (G, 1, Bp) i32 group-local ids;
+    x: (F, 1, Bp); y: (1, Bp); w: (G, 1, Bp).
 
-    Bp must be a multiple of ``tile_b`` and Mp of ``tile_m`` (ops.py pads
-    with w = 0 / leaf = -1).  Returns the merged dense forest.
+    The group count G is ``leaf.shape[0]``.  Bp must be a multiple of
+    ``tile_b`` and Mp_g of ``tile_m`` (ops.py pads with w = 0 / leaf = -1).
+    Returns the merged dense forest.
     """
-    F, rows, Mp, Cp = tab.shape
+    F, rows, GMp, Cp = tab.shape
     assert rows == FOREST_ROWS
-    Bp = x.shape[2]
-    assert Bp % tile_b == 0 and Mp % tile_m == 0
-    grid = (F, Mp // tile_m, Bp // tile_b)
+    G, Bp = leaf.shape[0], x.shape[2]
+    tiles = GMp // (G * tile_m)         # leaf tiles per group
+    assert tiles * G * tile_m == GMp and Bp % tile_b == 0
+    grid = (F, G, tiles, Bp // tile_b)
 
     kernel = functools.partial(_qo_update_leaves_kernel,
                                n_bins=n_bins, tile_m=tile_m)
+    slab = lambda f, g, j, i: (f, 0, g * tiles + j, 0)
     return pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # leaf ids
-            pl.BlockSpec((1, 1, tile_b), lambda f, j, i: (f, 0, i)),  # x feature
-            pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # y
-            pl.BlockSpec((1, tile_b), lambda f, j, i: (0, i)),    # w
-            pl.BlockSpec((1, FOREST_ROWS, tile_m, Cp),
-                         lambda f, j, i: (f, 0, j, 0)),           # seed tables
+            pl.BlockSpec((1, 1, tile_b), lambda f, g, j, i: (g, 0, i)),  # leaf ids
+            pl.BlockSpec((1, 1, tile_b), lambda f, g, j, i: (f, 0, i)),  # x feature
+            pl.BlockSpec((1, tile_b), lambda f, g, j, i: (0, i)),        # y
+            pl.BlockSpec((1, 1, tile_b), lambda f, g, j, i: (g, 0, i)),  # w
+            pl.BlockSpec((1, FOREST_ROWS, tile_m, Cp), slab),  # seed tables
         ],
-        out_specs=pl.BlockSpec((1, FOREST_ROWS, tile_m, Cp),
-                               lambda f, j, i: (f, 0, j, 0)),
-        out_shape=jax.ShapeDtypeStruct((F, FOREST_ROWS, Mp, Cp), jnp.float32),
+        out_specs=pl.BlockSpec((1, FOREST_ROWS, tile_m, Cp), slab),
+        out_shape=jax.ShapeDtypeStruct((F, FOREST_ROWS, GMp, Cp), jnp.float32),
         interpret=interpret,
     )(leaf, x, y, w, tab)

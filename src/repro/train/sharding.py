@@ -420,13 +420,13 @@ def _dp_local_shard(fcfg, forest, delta, keys, X, y):
     cdf = jnp.asarray(fr._poisson_cdf(fcfg.lam), jnp.float32)
     w = jax.vmap(lambda k: fr._poisson_weights(k, cdf, (B,)))(wkeys)  # (T, B)
 
-    gl, leaf, batch_leaf = fr._fused_route_stats(fcfg, trees, X, y, w)
+    leaf, batch_leaf = fr._fused_route_stats(fcfg, trees, X, y, w)
     # prequential member errors on the raw local rows, pre-absorb
     yhat = jnp.take_along_axis(trees["ystats"]["mean"], leaf, axis=1)
     err = stats.from_batch((yhat - y[None, :]) ** 2, axis=1)      # (T,)
 
     ao_y, ao_sum_x = fr._fused_absorb_tables(
-        fcfg, delta["ao_y"], delta["ao_sum_x"], trees, gl, X, y, w)
+        fcfg, delta["ao_y"], delta["ao_sum_x"], trees, leaf, X, y, w)
     return {
         "ystats": stats.merge(delta["ystats"], batch_leaf),
         "ao_y": ao_y,

@@ -4,14 +4,22 @@ Property coverage demanded by the batched-QO pipeline: ragged batches
 (B not a tile multiple), empty leaves (no routed rows), and tables with a
 single occupied bin (no valid boundary).  Acceptance bar: bin counts and
 VR scores within 1e-4 of the per-table :mod:`repro.core.qo` oracle.
+
+The absorb's group form (one group of tables per forest member) is held
+to the same oracle, to its own groups, and to the one-group call's bits
+in ``tests/goldens/absorb_goldens.npz`` (``tools/make_absorb_goldens.py``,
+generated from the code before the group form).
 """
 import functools
+import math
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core import forest as fr
 from repro.core import hoeffding as ht
 from repro.core import stats
 from repro.data import synth
@@ -199,3 +207,232 @@ def test_update_stream_matches_batch_loop():
     np.testing.assert_allclose(np.asarray(s_loop["ystats"]["mean"]),
                                np.asarray(s_scan["ystats"]["mean"]),
                                rtol=1e-5, atol=1e-5)
+
+
+# --------------------------------------------------------------------------
+# the absorb's group form: one group of tables per forest member
+# --------------------------------------------------------------------------
+
+GOLDENS = os.path.join(os.path.dirname(__file__), "goldens",
+                       "absorb_goldens.npz")
+STAT_KEYS = ("n", "mean", "m2", "sum_x")
+
+
+def _seeded_tables(rng, shape):
+    """Random occupied/empty bins; radius/origin over the leading axes."""
+    n = rng.integers(0, 5, shape).astype(np.float32)
+    occ = n > 0
+    pick = lambda a: jnp.asarray(np.where(occ, a, 0).astype(np.float32))
+    ao_y = {"n": jnp.asarray(n), "mean": pick(rng.normal(0, 2, shape)),
+            "m2": pick(rng.gamma(1.0, 1.0, shape))}
+    return (ao_y, pick(rng.normal(0, 1, shape)),
+            jnp.asarray(rng.uniform(0.05, 0.4, shape[:-1]).astype(np.float32)),
+            jnp.asarray(rng.normal(0, 0.5, shape[:-1]).astype(np.float32)))
+
+
+def _assert_close_per_table(got, want, key, rel=1e-6):
+    """Every table (the last axis) within ``rel`` of its own largest
+    magnitude: the bins of a table whose mean nearly cancels are held to
+    the table's scale, not to their own."""
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    scale = np.abs(want).max(-1, keepdims=True)
+    bad = np.abs(got - want) > rel * scale
+    assert not bad.any(), (f"{key}: {bad.sum()} bins off by more than "
+                           f"{rel} of their table's scale")
+
+
+def _as_dict(ao_y, ao_sum_x):
+    return {**{k: np.asarray(v) for k, v in ao_y.items()},
+            "sum_x": np.asarray(ao_sum_x)}
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+@pytest.mark.parametrize("M", [13, 130])
+@pytest.mark.parametrize("G", [1, 3])
+def test_grouped_update_matches_oracle(G, M, backend, rng):
+    """Each group's tables == the oracle over that group's rows alone:
+    M not a multiple of the leaf tile, B = 300 not a multiple of the
+    batch tile, weight-0 rows, and (G = 3) one group with no rows."""
+    F, C, B = 2, 48, 300
+    ao_y, ao_sum_x, ao_radius, ao_origin = _seeded_tables(rng, (G, M, F, C))
+    leaf = jnp.asarray(rng.integers(0, M, (G, B)), jnp.int32)
+    X = jnp.asarray(rng.normal(0, 1, (B, F)).astype(np.float32))
+    y = jnp.asarray(rng.normal(0, 2, B).astype(np.float32))
+    w = np.where(rng.uniform(size=(G, B)) < 0.2, 0.0,
+                 rng.uniform(0.1, 3.0, (G, B))).astype(np.float32)
+    if G > 1:
+        w[1] = 0.0                                   # a group with no rows
+    w = jnp.asarray(w)
+
+    ky, ksx = ops.forest_update(ao_y, ao_sum_x, ao_radius, ao_origin,
+                                leaf, X, y, w, backend=backend)
+    assert ksx.shape == (G, M, F, C)
+    for g in range(G):
+        ry, rsx = ref.forest_update_ref(
+            jax.tree.map(lambda a: a[g], ao_y), ao_sum_x[g], ao_radius[g],
+            ao_origin[g], leaf[g], X, y, w[g])
+        for k in ("n", "mean", "m2"):
+            np.testing.assert_allclose(np.asarray(ky[k][g]), np.asarray(ry[k]),
+                                       atol=TOL, rtol=TOL,
+                                       err_msg=f"group {g}: {k}")
+        np.testing.assert_allclose(np.asarray(ksx[g]), np.asarray(rsx),
+                                   atol=TOL, rtol=TOL, err_msg=f"group {g}")
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+def test_grouped_rows_stay_in_their_group(backend, rng):
+    """Rows of group g never reach group h's tables: empty tables stay
+    exactly empty in the group whose rows all weigh 0, and every other
+    group holds exactly its own weight, once per feature."""
+    G, M, F, C, B = 3, 13, 2, 16, 300
+    ao_y = stats.init((G, M, F, C))
+    ao_sum_x = jnp.zeros((G, M, F, C))
+    ao_radius = jnp.full((G, M, F), 0.25, jnp.float32)
+    ao_origin = jnp.zeros((G, M, F), jnp.float32)
+    leaf = jnp.asarray(rng.integers(0, M, (G, B)), jnp.int32)
+    X = jnp.asarray(rng.normal(0, 1, (B, F)).astype(np.float32))
+    y = jnp.asarray(rng.normal(0, 2, B).astype(np.float32))
+    w = rng.integers(1, 4, (G, B)).astype(np.float32)   # integer weights:
+    w[1] = 0.0                                          # exact f32 sums
+    ky, ksx = ops.forest_update(ao_y, ao_sum_x, ao_radius, ao_origin,
+                                leaf, X, y, jnp.asarray(w), backend=backend)
+    for k in ("n", "mean", "m2"):
+        assert not np.asarray(ky[k][1]).any(), k
+    assert not np.asarray(ksx[1]).any()
+    n = np.asarray(ky["n"]).sum(axis=(1, 3))                    # (G, F)
+    np.testing.assert_array_equal(n, np.repeat(w.sum(1)[:, None], F, 1))
+
+
+@pytest.fixture(scope="module")
+def goldens():
+    with np.load(GOLDENS) as g:
+        return dict(g)
+
+
+def _golden_absorb(goldens, part, leaf, X, y, w, backend, tabs=None):
+    tabs = tabs or {k: goldens[f"{part}_in_{k}"]
+                    for k in STAT_KEYS + ("radius", "origin")}
+    out = ops.forest_update({k: jnp.asarray(tabs[k]) for k in STAT_KEYS[:3]},
+                            jnp.asarray(tabs["sum_x"]),
+                            jnp.asarray(tabs["radius"]),
+                            jnp.asarray(tabs["origin"]), leaf, X, y, w,
+                            backend=backend)
+    return _as_dict(*out)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+@pytest.mark.parametrize("part", ["single", "folded"])
+def test_one_group_absorb_bit_identical_to_golden(goldens, part, backend):
+    """The one-group call — a single tree's, or a forest folded into one
+    table axis — reproduces the pre-group-form bits on both backends."""
+    X, y = goldens["X"], goldens["y"]
+    if part == "single":
+        got = _golden_absorb(goldens, "single", goldens["single_leaf"], X, y,
+                             goldens["w"], backend)
+    else:
+        G, M = goldens["grouped_leaf"].shape[0], \
+            goldens["grouped_in_n"].shape[0] // 3
+        gl = (np.arange(G)[:, None] * M + goldens["grouped_leaf"]).reshape(-1)
+        got = _golden_absorb(goldens, "grouped", gl, np.tile(X, (G, 1)),
+                             np.tile(y, G), goldens["grouped_w"].reshape(-1),
+                             backend)
+    for k in STAT_KEYS:
+        np.testing.assert_array_equal(got[k], goldens[f"{part}_{backend}_{k}"],
+                                      err_msg=k)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+def test_group_form_matches_folded_golden(goldens, backend):
+    """The group form against the folded call it replaces in the forest:
+    bit-identical on jnp (which folds internally), within 1e-6 of each
+    table's scale on the kernel path (which skips the folded call's
+    empty visits, each of which could move a mean by an ulp)."""
+    G = goldens["grouped_leaf"].shape[0]
+    M = goldens["grouped_in_n"].shape[0] // G
+    tabs = {k: goldens[f"grouped_in_{k}"].reshape(
+        (G, M) + goldens[f"grouped_in_{k}"].shape[1:])
+        for k in STAT_KEYS + ("radius", "origin")}
+    got = _golden_absorb(goldens, "grouped", goldens["grouped_leaf"],
+                         goldens["X"], goldens["y"], goldens["grouped_w"],
+                         backend, tabs=tabs)
+    for k in STAT_KEYS:
+        want = goldens[f"folded_{backend}_{k}"]
+        got_k = got[k].reshape(want.shape)
+        if backend == "jnp":
+            np.testing.assert_array_equal(got_k, want, err_msg=k)
+        else:
+            _assert_close_per_table(got_k, want, k)
+
+
+@pytest.mark.parametrize("backend", ["interpret", "jnp"])
+def test_forest_update_matches_folded_golden(goldens, backend):
+    """A small forest learning three batches: every table, leaf mean and
+    node count against the forest that absorbed through the folded call —
+    bit-identical on jnp, within 1e-6 of each table's scale on the kernel
+    path."""
+    cfg = fr.ForestConfig(tree=ht.HTRConfig(
+        n_features=3, max_nodes=15, n_bins=16, grace_period=100,
+        max_depth=4, r0=0.3, split_backend=backend), n_trees=3)
+    state = fr.init_forest(cfg, jax.random.PRNGKey(3))
+    upd = jax.jit(lambda s, Xb, yb: fr.update(cfg, s, Xb, yb)[0])
+    X, y = goldens["forest_X"], goldens["forest_y"]
+    for s in range(3):
+        state = upd(state, X[s * 256:(s + 1) * 256], y[s * 256:(s + 1) * 256])
+    trees = state["trees"]
+    got = {**_as_dict(trees["ao_y"], trees["ao_sum_x"]),
+           "ystats_mean": np.asarray(trees["ystats"]["mean"])}
+    np.testing.assert_array_equal(np.asarray(trees["n_nodes"]),
+                                  goldens[f"forest_{backend}_n_nodes"])
+    for k, v in got.items():
+        want = goldens[f"forest_{backend}_{k}"]
+        if backend == "jnp":
+            np.testing.assert_array_equal(v, want, err_msg=k)
+        else:
+            _assert_close_per_table(v, want, k)
+
+
+def _pallas_grids(jaxpr):
+    """Grid of every ``pallas_call`` in a jaxpr, nested jaxprs included."""
+    from jax.extend import core as jex
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else [v]:
+                if isinstance(sub, jex.ClosedJaxpr):
+                    grids += _pallas_grids(sub.jaxpr)
+                elif isinstance(sub, jex.Jaxpr):
+                    grids += _pallas_grids(sub)
+    return grids
+
+
+def test_absorb_grid_walks_member_by_member():
+    """At the benchmark cells' shapes (T = 10 members of M = 1,023 tables,
+    F = 10, C = 64, B = 4,096) the forest's absorb stage launches one
+    kernel of 12,800 grid steps, where the folded call it replaces
+    walks 128,000: the tree axis is engaged."""
+    T, M, F, C, B = 10, 1023, 10, 64, 4096
+    cfg = fr.ForestConfig(tree=ht.HTRConfig(
+        n_features=F, max_nodes=M, n_bins=C, split_backend="interpret"),
+        n_trees=T)
+    trees = jax.eval_shape(lambda: fr.init_forest(
+        cfg, jax.random.PRNGKey(0)))["trees"]
+    sd = jax.ShapeDtypeStruct
+    leaf, w = sd((T, B), jnp.int32), sd((T, B), jnp.float32)
+    X, y = sd((B, F), jnp.float32), sd((B,), jnp.float32)
+    grouped = jax.make_jaxpr(
+        lambda tr, l, X, y, w: fr._fused_absorb_tables(
+            cfg, tr["ao_y"], tr["ao_sum_x"], tr, l, X, y, w))(
+        trees, leaf, X, y, w)
+    assert [math.prod(g) for g in _pallas_grids(grouped.jaxpr)] == [12800]
+
+    flat = lambda a: sd((T * M,) + a.shape[2:], a.dtype)
+    folded = jax.make_jaxpr(
+        lambda ay, sx, r, o, gl, X, y, w: ops.forest_update(
+            ay, sx, r, o, gl, X, y, w, backend="interpret"))(
+        jax.tree.map(flat, trees["ao_y"]), flat(trees["ao_sum_x"]),
+        flat(trees["ao_radius"]), flat(trees["ao_origin"]),
+        sd((T * B,), jnp.int32), sd((T * B, F), jnp.float32),
+        sd((T * B,), jnp.float32), sd((T * B,), jnp.float32))
+    assert [math.prod(g) for g in _pallas_grids(folded.jaxpr)] == [128000]

@@ -1,7 +1,8 @@
 """Compile guard: the forest's Pallas kernels compile for a TPU v5e.
 
 Each case lowers one kernel at the widths ``chip_smoke.py`` runs (F = 10
-features, T·M = 16 x 1023 folded tables, C = 64 bins, B = 4096 rows) for
+features, T·M = 16 x 1023 tables, folded, or one group per member in the
+absorb; C = 64 bins, B = 4096 rows) for
 one chip of a *described* ``v5e:2x2`` topology and asserts that the
 compiled program holds the Mosaic kernel (``tpu_custom_call``).  Nothing
 runs and no chip is needed: this catches what interpret mode cannot — a
@@ -32,6 +33,7 @@ from repro.kernels.sketch_compact import sketch_compact_pallas
 
 T, M, F, C, B = 16, 1023, 10, 64, 4096
 MP, CP = round_up(T * M, 128), round_up(C, 128)   # ops.pack_forest's layout
+MP_G = round_up(M, 128)                 # one member's tables in the absorb
 ROWS = round_up(T * M * F, 256)                   # merge/compact row tiles
 PLIES = 12                                        # HTRConfig.max_depth
 
@@ -67,8 +69,8 @@ f32, i32 = jnp.float32, jnp.int32
 KERNELS = {
     "qo_update_leaves": (
         functools.partial(qo_update_leaves_pallas, n_bins=C),
-        [((F, FOREST_ROWS, MP, CP), f32), ((1, B), i32), ((F, 1, B), f32),
-         ((1, B), f32), ((1, B), f32)]),
+        [((F, FOREST_ROWS, T * MP_G, CP), f32), ((T, 1, B), i32),
+         ((F, 1, B), f32), ((1, B), f32), ((T, 1, B), f32)]),
     "qo_query_batched": (
         qo_query_batched_pallas, [((F, FOREST_ROWS, MP, CP), f32)]),
     "qo_route": (

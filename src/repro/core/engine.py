@@ -62,7 +62,7 @@ import functools
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, NamedTuple, Optional
 
 import jax
 import numpy as np
@@ -153,6 +153,24 @@ def _learn(cfg_model, state, X, y):
     return ht.update(cfg_model, state, X, y)
 
 
+class _OneDevice(NamedTuple):
+    """A tree or forest config as the engine's trainer: ``_learn`` on
+    one device, where every step is a sync, nothing moves between
+    devices and the state is the model."""
+    cfg: Any
+    sync_every = 1
+
+    def update(self, state, X, y):
+        return _learn(self.cfg, state, X, y), {}
+
+    def model(self, state):
+        return state
+
+    @property
+    def exchange(self):
+        return {"dp_syncs": 0, "dp_exchange_bytes": 0, "dp_exchange_s": 0.0}
+
+
 class _Published:
     """Immutable published record — the single swapped reference.
 
@@ -174,10 +192,19 @@ class _Published:
 class ServingEngine:
     """Concurrent train-and-serve over one model lineage.
 
-    ``cfg_model``: a :class:`repro.core.forest.ForestConfig` (its
-    ``"trees"``-keyed state) or a :class:`repro.core.hoeffding.HTRConfig`
-    (single tree) — anything :func:`repro.core.serve.freeze` packs.
-    ``state``: the initial trained-or-fresh model pytree.
+    ``trainer``: what learns each batch.  A
+    :class:`repro.core.forest.ForestConfig` (its ``"trees"``-keyed state)
+    or a :class:`repro.core.hoeffding.HTRConfig` (single tree) trains on
+    one device through one compiled program (``_learn``); a
+    :class:`repro.train.sharding.DataParallelForest` trains one global
+    batch over its mesh.  Either way the engine calls
+    ``update(state, X, y) -> (state, aux)`` once a step and publishes
+    ``model(state)``, the state :func:`repro.core.serve.freeze` packs,
+    and reports the trainer's ``exchange`` counts in :meth:`metrics`.
+    The engine's ``sync_every`` must be a multiple of the trainer's
+    ``sync_every`` (``ValueError`` otherwise): a publish reads the forest
+    only where the trainer has just merged it.
+    ``state``: the initial trained-or-fresh trainer state.
     ``stream``: ``stream(step) -> (X, y) | None`` — a *deterministic*
     batch source indexed by trainer step (None = exhausted).  Indexing by
     step is what makes crash-recovery exact: after a restore to step s
@@ -191,11 +218,18 @@ class ServingEngine:
     never a cold start.
     """
 
-    def __init__(self, cfg_model, state, stream: Callable, *,
+    def __init__(self, trainer, state, stream: Callable, *,
                  cfg: EngineConfig = EngineConfig(),
                  checkpointer=None, injector: Optional[fl.FaultInjector] = None):
         self.cfg = cfg
-        self._model_cfg = cfg_model
+        self._model_cfg = trainer
+        self._trainer = _OneDevice(trainer) \
+            if isinstance(trainer, (fr.ForestConfig, ht.HTRConfig)) \
+            else trainer
+        if cfg.sync_every % self._trainer.sync_every:
+            raise ValueError(
+                f"engine sync_every {cfg.sync_every} is not a multiple of "
+                f"the trainer's sync_every {self._trainer.sync_every}")
         self._state = state
         self._stream = stream
         self._ckpt = checkpointer
@@ -248,10 +282,14 @@ class ServingEngine:
         live state back from the device (``serve.freeze.fetch``, which
         first waits for every step still queued there);
         ``publish_host_s`` the rest of every ``engine.publish`` span:
-        host work while the device has nothing queued."""
+        host work while the device has nothing queued.  A data-parallel
+        trainer's syncs add ``dp_syncs``, ``dp_exchange_bytes`` (deltas
+        gathered plus forest broadcast) and ``dp_exchange_s`` (host
+        seconds in the ``dp.gather`` and ``dp.broadcast`` spans)."""
         with self._m_lock:
             out = dict(self._metrics)
             out.update(self._errors)
+        out.update(self._trainer.exchange)
         out.update(self.staleness())
         return out
 
@@ -305,8 +343,9 @@ class ServingEngine:
             with self._pub_lock:
                 version = (self._published.version + 1) \
                     if self._published else 1
-            snap = sv.freeze(self._state, version=version,
-                             step=self._trainer_step, timings=timings)
+            snap = sv.freeze(self._trainer.model(self._state),
+                             version=version, step=self._trainer_step,
+                             timings=timings)
             return self._publish(snap)
 
     def publish(self, snap: sv.Snapshot) -> bool:
@@ -404,7 +443,7 @@ class ServingEngine:
 
     def _train_step(self, batch):
         X, y = batch
-        return _learn(self._model_cfg, self._state, X, y)
+        return self._trainer.update(self._state, X, y)[0]
 
     def recover(self):
         """Crash recovery: restore the newest valid checkpoint (or fall

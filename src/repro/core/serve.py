@@ -197,7 +197,10 @@ def freeze(state, *, version: int = 0, step: int = 0,
     tree) or a :func:`repro.core.forest.init_forest` pytree (detected by
     its ``"trees"`` key; the carried ``vote_w`` is read for free).  A
     host-side packing step — arrays must be concrete (freeze at the
-    train/serve boundary, not inside a jit).  Capacity is trimmed to the
+    train/serve boundary, not inside a jit) — whose planes all go
+    through the host, so the snapshot lives on the default device
+    wherever the state lies (a data-parallel forest is replicated over
+    its mesh).  Capacity is trimmed to the
     realized node count (power-of-two bucketed, min 8) and ``depth`` to
     the deepest realized leaf across members.
 
@@ -230,6 +233,10 @@ def freeze(state, *, version: int = 0, step: int = 0,
             is_leaf = np.asarray(trees["is_leaf"])
             mean = np.asarray(trees["ystats"]["mean"])
             n_nodes = np.asarray(trees["n_nodes"])
+            # through the host like every plane: a data-parallel forest's
+            # weights are replicated over its mesh, and a snapshot lives
+            # on one device
+            vote_w = np.asarray(vote_w, np.float32)
         t1 = time.perf_counter()
         with jax.profiler.TraceAnnotation("serve.freeze.reindex"):
             Mr = 8
@@ -244,7 +251,7 @@ def freeze(state, *, version: int = 0, step: int = 0,
             snap = Snapshot(
                 feature=stack(0), threshold=stack(1), child=stack(2),
                 is_leaf=stack(3), leaf_mean=stack(4),
-                vote_w=jnp.asarray(vote_w, jnp.float32),
+                vote_w=jnp.asarray(vote_w),
                 depth=max(p[5] for p in packed), single=single,
                 version=jnp.asarray(version, jnp.int32),
                 step=jnp.asarray(step, jnp.int32))

@@ -17,8 +17,10 @@ Logical mapping:
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
-from typing import Any, Dict, NamedTuple, Tuple
+import time
+from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -503,18 +505,19 @@ def _dp_reduce_deltas(fcfg, delta):
             "err": stats.merge(a["err"], b["err"]),
         }
 
-    while delta["ao_sum_x"].shape[0] > 1:
-        k = delta["ao_sum_x"].shape[0]
-        half = k // 2
-        a = jax.tree.map(lambda x: x[:half], delta)
-        b = jax.tree.map(lambda x: x[half:2 * half], delta)
-        m = merge_pair(a, b)
-        if k % 2:
-            delta = jax.tree.map(
-                lambda x, t: jnp.concatenate([x, t[-1:]], 0), m, delta)
-        else:
-            delta = m
-    return jax.tree.map(lambda x: x[0], delta)
+    with jax.named_scope("dp.merge"):
+        while delta["ao_sum_x"].shape[0] > 1:
+            k = delta["ao_sum_x"].shape[0]
+            half = k // 2
+            a = jax.tree.map(lambda x: x[:half], delta)
+            b = jax.tree.map(lambda x: x[half:2 * half], delta)
+            m = merge_pair(a, b)
+            if k % 2:
+                delta = jax.tree.map(
+                    lambda x, t: jnp.concatenate([x, t[-1:]], 0), m, delta)
+            else:
+                delta = m
+        return jax.tree.map(lambda x: x[0], delta)
 
 
 def _dp_apply_sync(fcfg, forest, merged):
@@ -540,28 +543,30 @@ def _dp_apply_sync(fcfg, forest, merged):
     F, C = fcfg.tree.n_features, fcfg.tree.observer_bins()
     table_merge = kops.sketch_merge \
         if fcfg.tree.observer_backend == "sketch" else kops.forest_merge
-    trees = forest["trees"]
-    trees = dict(trees,
-                 ystats=stats.merge(trees["ystats"], merged["ystats"]),
-                 seen_since_attempt=trees["seen_since_attempt"]
-                 + merged["ystats"]["n"])
-    fold = lambda x: x.reshape((T * M, F, C))
-    ao_y, ao_sum_x = table_merge(
-        jax.tree.map(fold, trees["ao_y"]), fold(trees["ao_sum_x"]),
-        jax.tree.map(fold, merged["ao_y"]), fold(merged["ao_sum_x"]),
-        backend=fcfg.tree.split_backend)
-    unfold = lambda x: x.reshape((T, M) + x.shape[1:])
-    trees = dict(trees, ao_y=jax.tree.map(unfold, ao_y),
-                 ao_sum_x=unfold(ao_sum_x))
-    trees = fr._fused_member_attempt(fcfg, trees, forest["feat_mask"])
+    with jax.named_scope("dp.apply"):
+        trees = forest["trees"]
+        trees = dict(trees,
+                     ystats=stats.merge(trees["ystats"], merged["ystats"]),
+                     seen_since_attempt=trees["seen_since_attempt"]
+                     + merged["ystats"]["n"])
+        fold = lambda x: x.reshape((T * M, F, C))
+        ao_y, ao_sum_x = table_merge(
+            jax.tree.map(fold, trees["ao_y"]), fold(trees["ao_sum_x"]),
+            jax.tree.map(fold, merged["ao_y"]), fold(merged["ao_sum_x"]),
+            backend=fcfg.tree.split_backend)
+        unfold = lambda x: x.reshape((T, M) + x.shape[1:])
+        trees = dict(trees, ao_y=jax.tree.map(unfold, ao_y),
+                     ao_sum_x=unfold(ao_sum_x))
+        trees = fr._fused_member_attempt(fcfg, trees, forest["feat_mask"])
 
-    err_win = stats.merge(forest["err_win"], merged["err"])
-    state = dict(forest, trees=trees, err_win=err_win,
-                 err_ewma=jnp.where(err_win["n"] > 0, err_win["mean"], 0.0))
-    state["vote_w"] = fr.vote_weights(fcfg, state)
-    aux = {"mass": merged["ystats"]["n"].sum(),
-           "member_mse": state["err_ewma"],
-           "n_nodes": trees["n_nodes"]}
+        err_win = stats.merge(forest["err_win"], merged["err"])
+        state = dict(forest, trees=trees, err_win=err_win,
+                     err_ewma=jnp.where(err_win["n"] > 0, err_win["mean"],
+                                        0.0))
+        state["vote_w"] = fr.vote_weights(fcfg, state)
+        aux = {"mass": merged["ystats"]["n"].sum(),
+               "member_mse": state["err_ewma"],
+               "n_nodes": trees["n_nodes"]}
     return state, aux
 
 
@@ -570,9 +575,11 @@ def _dp_sync_jit(fcfg):
     """ONE cached jit of reduce + apply per config — shared by the
     sharded trainer and the single-device reference, so the sync math of
     the two paths is literally the same compiled program (the §4.1
-    bit-identity pin)."""
-    return jax.jit(lambda forest, delta: _dp_apply_sync(
-        fcfg, forest, _dp_reduce_deltas(fcfg, delta)))
+    bit-identity pin).  A profiler names its runs ``jit_dp_sync``."""
+    def dp_sync(forest, delta):
+        return _dp_apply_sync(fcfg, forest, _dp_reduce_deltas(fcfg, delta))
+
+    return jax.jit(dp_sync)
 
 
 @functools.lru_cache(maxsize=None)
@@ -641,7 +648,13 @@ def _dp_gather_int8(fcfg, delta, axis: str):
     }
 
 
-class DataParallelForest(NamedTuple):
+def _no_exchange() -> Dict[str, Any]:
+    """A trainer's exchange counts before its first sync."""
+    return {"dp_syncs": 0, "dp_exchange_bytes": 0, "dp_exchange_s": 0.0}
+
+
+@dataclasses.dataclass(frozen=True)
+class DataParallelForest:
     """The §4.1 trainer's entry points (both builders return one):
 
     ``init(key) -> dpstate``; ``update(dpstate, X, y) -> (dpstate,
@@ -650,17 +663,35 @@ class DataParallelForest(NamedTuple):
     — a whole (S, B, F) window of local batches in ONE dispatch followed
     by an unconditional sync (the deployment shape: shards run
     autonomously between boundaries); ``predict(dpstate, X) -> (B,)``.
+    It unpacks as those four: ``init, update, window, predict = ...``.
+
+    ``sync_every``: the global batches between two syncs.  ``exchange``:
+    what every sync since the build moved between devices — ``dp_syncs``,
+    ``dp_exchange_bytes`` (the shard deltas gathered to the root device
+    and the merged forest broadcast back, counted from the shapes) and
+    ``dp_exchange_s`` (host seconds inside the ``dp.gather`` and
+    ``dp.broadcast`` spans).  A :class:`repro.core.engine.ServingEngine`
+    drives either trainer through ``update`` and publishes :meth:`model`.
     """
-    init: Any
-    update: Any
-    update_window: Any
-    predict: Any
+    init: Callable
+    update: Callable
+    update_window: Callable
+    predict: Callable
+    sync_every: int
+    exchange: Dict[str, Any]
+
+    def __iter__(self):
+        return iter((self.init, self.update, self.update_window,
+                     self.predict))
+
+    def model(self, dpstate):
+        """The replicated forest state a publish freezes."""
+        return dpstate["forest"]
 
 
 def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
                                sync_every: int = 1,
-                               compress: str | None = None,
-                               on_sync=None):
+                               compress: str | None = None):
     """Data-parallel stream scale-out (DESIGN.md §4.1).
 
     The third and last sharding axis: :func:`build_sharded_forest`
@@ -685,27 +716,29 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
     (§4.2; lossy, ~4x less wire traffic).  Requires a kernel-capable
     ``split_backend`` (not ``"oracle"``).
 
-    Returns a :class:`DataParallelForest` named tuple:
+    Returns a :class:`DataParallelForest`:
 
     * ``init(key) -> dpstate`` — device-placed trainer state;
-    * ``update(dpstate, X, y) -> (dpstate, aux | None)`` — learn one
-      global batch of B rows (D must divide B; rows shard
-      contiguously).  ``aux`` is None between syncs and
-      ``{"mass", "member_mse", "n_nodes"}`` at a boundary;
+    * ``update(dpstate, X, y) -> (dpstate, aux | None)``
+      — learn one global batch of B rows, placed from the host straight
+      onto the mesh (D must divide B; device d gets the d-th contiguous
+      B/D rows, as ``P(axis)`` gives them).  ``aux`` is None between
+      syncs and ``{"mass", "member_mse", "n_nodes"}`` at a boundary;
     * ``update_window(dpstate, Xw, yw) -> (dpstate, aux)`` — a whole
       (S, B, F) window of local batches in ONE dispatch, then an
       unconditional sync;
     * ``predict(dpstate, X) -> (B,)`` — request-sharded vote over the
       replicated forest (no collectives; D must divide B).
 
-    ``on_sync``: optional ``on_sync(forest_state, step, aux)`` callback
-    fired at every sync boundary with the freshly merged (replicated)
-    forest — the **publish boundary** of the continuous-serving engine
-    (DESIGN.md §5.6): a
-    :class:`repro.core.engine.ServingEngine`'s publisher hooks here
-    (``freeze`` + validated atomic swap), so serving freshness rides the
-    ``sync_every`` cadence directly.  Exceptions out of ``on_sync`` are
-    the CALLER's (a publish failure must not poison training).
+    A :class:`repro.core.engine.ServingEngine` drives ``update`` and
+    publishes ``model(dpstate)`` every engine ``sync_every`` steps, a
+    multiple of this ``sync_every`` (DESIGN.md §5.6).
+
+    Each step is named in a profiler trace: ``dp.local`` (the local
+    step's dispatch), and at a sync ``dp.gather`` (the deltas to the root
+    device), ``dp.sync`` (merge and apply there, whose ops sit under the
+    scopes ``dp.merge`` and ``dp.apply``) and ``dp.broadcast`` (the
+    merged forest back to every device).
     """
     from repro.core import forest as fr
 
@@ -740,23 +773,29 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
         in_specs=(fspec, dspec, kspec, P(None, axis, None), P(None, axis)),
         out_specs=(dspec, kspec), check_vma=False))
 
+    nbytes = lambda t: sum(a.size * a.dtype.itemsize
+                           for a in jax.tree.leaves(t))
     if compress == "int8":
-        gather = jax.jit(jax.shard_map(
+        psum = jax.jit(jax.shard_map(
             lambda delta: _dp_gather_int8(
                 fcfg, jax.tree.map(lambda a: a[0], delta), axis),
             mesh=mesh, in_specs=(dspec,),
             out_specs=repl(jax.eval_shape(
                 lambda d: jax.tree.map(lambda a: a[0], d),
                 abstract["delta"])), check_vma=False))
-        sync = lambda forest, delta: _dp_apply_jit(fcfg)(
-            jax.device_put(forest, root),
-            jax.device_put(gather(delta), root))
+        gather = lambda delta: jax.device_put(psum(delta), root)
+        reduce_apply = _dp_apply_jit(fcfg)
+        gathered = 0          # the psum moved it; the root's copy is local
     else:
         # the gather to the root device is the collective; reduce + apply
         # run there through the SAME jit as the reference, and the merged
         # forest is broadcast back (``_synced``)
-        sync = lambda forest, delta: _dp_sync_jit(fcfg)(
-            jax.device_put(forest, root), jax.device_put(delta, root))
+        gather = lambda delta: jax.device_put(delta, root)
+        reduce_apply = _dp_sync_jit(fcfg)
+        gathered = nbytes(abstract["delta"]) // D * (D - 1)
+    exchange_bytes = gathered + nbytes(abstract["forest"]) * (D - 1)
+    rows_in = (NamedSharding(mesh, P(axis, None)),
+               NamedSharding(mesh, P(axis)))
 
     zero_delta = jax.device_put(_dp_init_delta(fcfg, D), delta_shard)
 
@@ -770,17 +809,31 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
             "step": 0,
         }
 
+    exchange = _no_exchange()
+
     def _synced(dpstate, delta, keys, step):
-        forest, aux = sync(dpstate["forest"], delta)
-        forest = jax.device_put(forest, forest_repl)
-        if on_sync is not None:
-            on_sync(forest, step, aux)        # the publish boundary
+        t0 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("dp.gather"):
+            forest = jax.device_put(dpstate["forest"], root)
+            delta = gather(delta)
+        t1 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("dp.sync"):
+            forest, aux = reduce_apply(forest, delta)
+        t2 = time.perf_counter()
+        with jax.profiler.TraceAnnotation("dp.broadcast"):
+            forest = jax.device_put(forest, forest_repl)
+        t3 = time.perf_counter()
+        exchange["dp_syncs"] += 1
+        exchange["dp_exchange_bytes"] += exchange_bytes
+        exchange["dp_exchange_s"] += (t1 - t0) + (t3 - t2)
         return {"forest": forest,
                 "delta": zero_delta, "keys": keys, "step": step}, aux
 
     def update_fn(dpstate, X, y):
-        delta, keys = local(dpstate["forest"], dpstate["delta"],
-                            dpstate["keys"], X, y)
+        X, y = jax.device_put((X, y), rows_in)
+        with jax.profiler.TraceAnnotation("dp.local"):
+            delta, keys = local(dpstate["forest"], dpstate["delta"],
+                                dpstate["keys"], X, y)
         step = dpstate["step"] + 1
         if step % sync_every:
             return dict(dpstate, delta=delta, keys=keys, step=step), None
@@ -798,11 +851,11 @@ def build_data_parallel_forest(fcfg, mesh: Mesh, axis: str = "data",
         check_vma=False))
 
     return DataParallelForest(init_fn, update_fn, update_window_fn,
-                              lambda dpstate, X: prd(dpstate["forest"], X))
+                              lambda dpstate, X: prd(dpstate["forest"], X),
+                              sync_every, exchange)
 
 
-def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1,
-                                  on_sync=None):
+def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1):
     """Single-device oracle of :func:`build_data_parallel_forest`.
 
     The SAME protocol with the shards run one after another on one
@@ -836,10 +889,11 @@ def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1,
                 for i in range(n_shards)]
         return jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
 
+    exchange = _no_exchange()
+
     def _synced(dpstate, delta, keys, step):
         forest, aux = _dp_sync_jit(fcfg)(dpstate["forest"], delta)
-        if on_sync is not None:
-            on_sync(forest, step, aux)        # the same publish boundary
+        exchange["dp_syncs"] += 1             # one device: nothing moves
         return {"forest": forest,
                 "delta": _dp_init_delta(fcfg, n_shards),
                 "keys": keys, "step": step}, aux
@@ -860,4 +914,4 @@ def build_data_parallel_reference(fcfg, n_shards: int, sync_every: int = 1,
         return fr.predict(fcfg, dpstate["forest"], X)
 
     return DataParallelForest(init_fn, update_fn, update_window_fn,
-                              predict_fn)
+                              predict_fn, sync_every, exchange)

@@ -304,36 +304,6 @@ def test_threaded_engine_serves_everything_admitted(tmp_path):
         _served_bit_identical(e, t)
 
 
-# -- publish boundary on the data-parallel trainer -------------------------
-
-def test_dp_on_sync_is_a_publish_boundary():
-    jnp_cfg = fr.ForestConfig(
-        tree=dataclasses.replace(TCFG, split_backend="jnp"), n_trees=4)
-    from repro.train import sharding as sh
-
-    calls = []
-
-    def on_sync(forest, step, aux):
-        calls.append((step, sv.freeze(forest, version=len(calls) + 1,
-                                      step=step)))
-
-    dp = sh.build_data_parallel_reference(jnp_cfg, n_shards=2,
-                                          sync_every=2, on_sync=on_sync)
-    st = dp.init(jax.random.PRNGKey(0))
-    for k in range(4):
-        st, aux = dp.update(st, jnp.asarray(X_ALL[k * B:(k + 1) * B]),
-                            jnp.asarray(Y_ALL[k * B:(k + 1) * B]))
-        assert (aux is None) == bool((k + 1) % 2)
-    assert [s for s, _ in calls] == [2, 4]   # fired exactly at boundaries
-    # the published snapshot IS the synced forest: frozen-at-boundary
-    # predictions match the trainer's own
-    step, snap = calls[-1]
-    np.testing.assert_array_equal(
-        np.asarray(sv.predict_snapshot(snap, jnp.asarray(X_ALL[:B]))),
-        np.asarray(dp.predict(st, jnp.asarray(X_ALL[:B]))))
-    assert int(np.asarray(snap.version)) == 2
-
-
 # -- snapshot identity round-trip ------------------------------------------
 
 def test_version_and_step_round_trip_through_checkpoint(tmp_path):
